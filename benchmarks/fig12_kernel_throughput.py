@@ -14,7 +14,7 @@ import numpy as np
 from .common import Row, nyx_like, timeit
 from repro.core import huffman
 from repro.kernels.zfp_block import ops as zfp_ops
-from repro.runtime.roofline import HBM_BW
+from repro.runtime import roofline
 
 # bytes touched per input byte (read in + write out + tables), per pipeline
 _TPU_TRAFFIC_FACTOR = {"zfp": 1.6, "huffman": 2.2, "mgard": 3.5}
@@ -44,7 +44,7 @@ def main() -> None:
         f"bps={keys.nbytes/t/1e6:.1f}MB/s").emit()
 
     for method, factor in _TPU_TRAFFIC_FACTOR.items():
-        proj = HBM_BW / factor
+        proj = roofline.peaks(roofline.V5E_KIND).hbm_bw / factor
         Row(f"fig12.{method}.tpu_v5e_roofline", 0.0,
             f"projected_bps={proj/1e9:.0f}GB/s (memory-bound, factor={factor})").emit()
 

@@ -14,7 +14,7 @@ hand-written backend:
 
 The portability contract of the paper carries over: a bitstream produced
 under any adapter decodes under any other (tested in
-``tests/test_portability.py``).
+``tests/test_api_portability.py``).
 
 Ops register one implementation per adapter in ``_REGISTRY``; callers go
 through :func:`dispatch` so the choice is a runtime config, exactly like the
@@ -123,20 +123,32 @@ def resolve(adapter: str | None) -> str:
     return adapter
 
 
+# Ops that have no Pallas kernel and run their ``xla`` implementation under
+# every adapter.  This is the one place where a kernel adapter may lower an
+# op through XLA; every other op must register its own implementation.
+#   huffman_pack_stream — the exclusive scan + segment-sum packing is already
+#                         the TPU-native formulation (core/bitstream.py).
+XLA_ONLY_OPS = frozenset({"huffman_pack_stream"})
+
+
+def lowering(op: str, adapter: str | None = None) -> str:
+    """The adapter whose implementation ``dispatch(op, adapter)`` runs."""
+    a = resolve(adapter)
+    if (op, a) in _REGISTRY:
+        return a
+    if op in XLA_ONLY_OPS and (op, XLA) in _REGISTRY:
+        return XLA
+    raise KeyError(f"op {op!r} has no implementation for adapter {a!r}")
+
+
 def dispatch(op: str, adapter: str | None = None) -> Callable:
     """Return the registered implementation of ``op`` for ``adapter``.
 
-    Falls back to the ``xla`` implementation if the requested adapter has no
-    specialised kernel for this op (mirrors the paper: not every algorithm
-    stage needs a hand-written kernel on every backend).
+    Only ops declared in :data:`XLA_ONLY_OPS` fall back to their ``xla``
+    implementation; any other op without a kernel for ``adapter`` raises,
+    so a missing kernel can never hide behind the XLA lowering.
     """
-    a = resolve(adapter)
-    impl = _REGISTRY.get((op, a))
-    if impl is None:
-        impl = _REGISTRY.get((op, XLA))
-    if impl is None:
-        raise KeyError(f"op {op!r} has no implementation (adapter={a!r})")
-    return impl
+    return _REGISTRY[(op, lowering(op, adapter))]
 
 
 def registered_ops() -> dict[tuple[str, str], Callable]:

@@ -46,7 +46,6 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from . import adapters
@@ -125,6 +124,9 @@ class ExecutionEngine:
         # implements donation)
         self._ws_stacks: dict[tuple, tuple] = {}
         self.shard_map_calls = 0
+        #: ids of the devices that held a stacked bucket's state — where the
+        #: data-axis fan-out actually ran
+        self.stack_devices: set[int] = set()
         self.sharded_leaves = 0
         self.sharded_decoded_leaves = 0
         self.transfer_h2d = 0
@@ -532,6 +534,7 @@ class ExecutionEngine:
         state = plan.pipeline.run_batched(
             {"data": stacked}, envs, self._mesh_segment_mapper(), transfers
         )
+        self._note_stack_devices(state)
         out = [
             codec.finish_container(
                 plan, envs[i], LeafView(state, i, envs[i], transfers)
@@ -573,6 +576,7 @@ class ExecutionEngine:
             [p[0] for p in prepared], envs, self._mesh_segment_mapper(),
             transfers,
         )
+        self._note_stack_devices(state)
         out = []
         for i, (_key, c) in enumerate(items):
             row = {key: arr[i] for key, arr in state.items()}
@@ -583,6 +587,14 @@ class ExecutionEngine:
             self.transfer_h2d += transfers.h2d
             self.transfer_d2h += transfers.d2h
         return out
+
+    def _note_stack_devices(self, state: dict) -> None:
+        ids = {
+            d.id for v in state.values() if isinstance(v, jax.Array)
+            for d in v.devices()
+        }
+        with self._lock:
+            self.stack_devices |= ids
 
     def _mesh_segment_mapper(self) -> Callable:
         """Wrap a vmapped pipeline segment in this engine's mesh shard_map.
@@ -639,11 +651,11 @@ class ExecutionEngine:
                         return outs, wstack
 
                     exe = adapters.donating_jit(
-                        shard_map(
+                        jax.shard_map(
                             wrapped, mesh=self.mesh,
                             in_specs=(state_specs, op_specs, ws_specs),
                             out_specs=(outs_specs, ws_specs),
-                            check_rep=False,
+                            check_vma=False,
                         ),
                         donate_argnums=(2,),
                     )
@@ -651,12 +663,12 @@ class ExecutionEngine:
                     ws_specs = tuple(
                         P(*([None] * np.ndim(a))) for a in ws_vals
                     )
-                    exe = shard_map(
+                    exe = jax.shard_map(
                         lambda s, o, w: vfn(s, o, w)[0],
                         mesh=self.mesh,
                         in_specs=(state_specs, op_specs, ws_specs),
                         out_specs=outs_specs,
-                        check_rep=False,
+                        check_vma=False,
                     )
                 with self._lock:
                     exe = self._smap_cache.setdefault(key, exe)
@@ -712,6 +724,7 @@ class ExecutionEngine:
             s.update(
                 backend=self.backend,
                 shard_map_calls=self.shard_map_calls,
+                stack_devices=tuple(sorted(self.stack_devices)),
                 sharded_leaves=self.sharded_leaves,
                 sharded_decoded_leaves=self.sharded_decoded_leaves,
                 transfer_h2d=self.transfer_h2d,

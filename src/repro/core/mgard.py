@@ -114,15 +114,27 @@ def interp_1d(coarse: jax.Array, axis: int) -> jax.Array:
     return jnp.moveaxis(out, 0, axis)
 
 
+# Floating-point contraction.  XLA may fuse ``a*b + c`` into one FMA, and
+# whether it does depends on how a program is fused, which differs between a
+# leaf's own program and the same code vmapped over a stacked bucket.  So the
+# transform below never adds an inexact product: the only multipliers are
+# powers of two (exact), and every inexact scale is a division, which is
+# never contracted.  Stacked and serial programs then round identically.
+
+
 def mass_mult_1d(x: jax.Array, axis: int, h: float) -> jax.Array:
-    """y = M x along ``axis``; M = h·tridiag(1/6, 2/3, 1/6), boundary h/3."""
+    """y = M x along ``axis``; M = h·tridiag(1/6, 2/3, 1/6), boundary h/3.
+
+    Computed as ``h·(4v + left + right)/6`` (``2v`` at the boundary): the
+    multipliers 4, 2 and ``h`` are powers of two, so no FMA can change it.
+    """
     v = jnp.moveaxis(x, axis, 0)
     n = v.shape[0]
     left = jnp.concatenate([jnp.zeros_like(v[:1]), v[:-1]], axis=0)
     right = jnp.concatenate([v[1:], jnp.zeros_like(v[:1])], axis=0)
-    diag = jnp.full((n,) + (1,) * (v.ndim - 1), 2.0 / 3.0, v.dtype)
-    diag = diag.at[0].set(1.0 / 3.0).at[-1].set(1.0 / 3.0)
-    y = h * (diag * v + (1.0 / 6.0) * (left + right))
+    diag = jnp.full((n,) + (1,) * (v.ndim - 1), 4.0, v.dtype)
+    diag = diag.at[0].set(2.0).at[-1].set(2.0)
+    y = h * ((diag * v + left + right) / 6.0)
     return jnp.moveaxis(y, 0, axis)
 
 
@@ -139,26 +151,22 @@ def restrict_1d(m: jax.Array, axis: int) -> jax.Array:
 
 
 @lru_cache(maxsize=None)
-def _thomas_coeffs(n: int, h: float) -> tuple[np.ndarray, np.ndarray]:
-    """Precompute Thomas forward-elimination constants for the 1D mass matrix.
-
-    Returns (cp, denom_inv): cp[i] = c_i / d'_i, denom_inv[i] = 1 / d'_i.
-    Data-independent (CMM-cached context), so the scan body is a single FMA.
-    """
-    a = np.full(n, h / 6.0)  # sub-diagonal
-    b = np.full(n, 2.0 * h / 3.0)
-    b[0] = b[-1] = h / 3.0
-    c = np.full(n, h / 6.0)  # super-diagonal
-    cp = np.zeros(n)
-    denom_inv = np.zeros(n)
-    denom = b[0]
-    denom_inv[0] = 1.0 / denom
-    cp[0] = c[0] / denom
+def _thomas_denoms(n: int, h: float) -> np.ndarray:
+    """Thomas forward-elimination pivots d'_i of the 1D mass matrix."""
+    a, b, c = h / 6.0, 2.0 * h / 3.0, h / 6.0
+    denom = np.zeros(n)
+    denom[0] = h / 3.0
     for i in range(1, n):
-        denom = b[i] - a[i] * cp[i - 1]
-        denom_inv[i] = 1.0 / denom
-        cp[i] = c[i] / denom
-    return cp, denom_inv
+        bi = h / 3.0 if i == n - 1 else b
+        denom[i] = bi - a * (c / denom[i - 1])
+    return denom
+
+
+@lru_cache(maxsize=None)
+def _thomas_coeffs(n: int, h: float) -> tuple[np.ndarray, np.ndarray]:
+    """Thomas constants (cp, denom_inv): cp[i] = c_i / d'_i, 1 / d'_i."""
+    denom = _thomas_denoms(n, h)
+    return (h / 6.0) / denom, 1.0 / denom
 
 
 def tridiag_solve_1d(rhs: jax.Array, axis: int, h: float) -> jax.Array:
@@ -169,31 +177,35 @@ def tridiag_solve_1d(rhs: jax.Array, axis: int, h: float) -> jax.Array:
     Fig. 3b).
     """
     n = rhs.shape[axis]
-    cp_np, dinv_np = _thomas_coeffs(n, h)
-    cp = jnp.asarray(cp_np, rhs.dtype)
-    dinv = jnp.asarray(dinv_np, rhs.dtype)
-    sub = h / 6.0
+    denom_np = _thomas_denoms(n, h)
+    dinv = jnp.asarray(1.0 / denom_np, rhs.dtype)
+    six_denom = jnp.asarray(6.0 * denom_np, rhs.dtype)
 
+    # The sub/super-diagonal h/6 enters as (x·h)/6: h is a power of two, so
+    # each subtracted term is a quotient and no step is FMA-contractible.
     def fwd(carry, inp):
         d_prev = carry
         r, di = inp
-        d = (r - sub * d_prev) * di
+        d = (r - (d_prev * h) / 6.0) * di
         return d, d
 
     v = jnp.moveaxis(rhs, axis, 0)
-    _, dp = jax.lax.scan(fwd, jnp.zeros_like(v[0]), (v, dinv.reshape(n, *([1] * (v.ndim - 1))) * jnp.ones_like(v)))
-    # NB: dinv broadcast trick — scan inputs must share leading dim.
+    bshape = (n,) + (1,) * (v.ndim - 1)
+    _, dp = jax.lax.scan(
+        fwd, jnp.zeros_like(v[0]), (v, dinv.reshape(bshape) * jnp.ones_like(v))
+    )
+    # NB: broadcast trick — scan inputs must share the leading dim.
 
     def back(carry, inp):
         x_next = carry
-        d, cpi = inp
-        x = d - cpi * x_next
+        d, sd = inp
+        x = d - (x_next * h) / sd  # cp_i·x = (h/6)/d'_i·x
         return x, x
 
     _, xs = jax.lax.scan(
         back,
         jnp.zeros_like(v[0]),
-        (dp, cp.reshape(n, *([1] * (v.ndim - 1))) * jnp.ones_like(v)),
+        (dp, six_denom.reshape(bshape) * jnp.ones_like(v)),
         reverse=True,
     )
     return jnp.moveaxis(xs, 0, axis)

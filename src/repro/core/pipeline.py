@@ -415,13 +415,10 @@ class ChunkedPipeline:
         )
         plan = None
         if self.tuner is not None:
-            try:
-                plan = self.tuner(
-                    int(data.size), int(data.dtype.itemsize),
-                    str(data.dtype), fixed_elems,
-                )
-            except Exception:
-                plan = None
+            plan = self.tuner(
+                int(data.size), int(data.dtype.itemsize),
+                str(data.dtype), fixed_elems,
+            )
         if plan is None:
             plan = tuner_mod.heuristic_plan(
                 int(data.size), int(data.dtype.itemsize),

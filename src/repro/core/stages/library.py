@@ -158,7 +158,12 @@ class MgardDecorrelate(Stage):
     def invert(self, env: TraceEnv, state: dict) -> dict:
         from .. import mgard
 
-        out = mgard.recompose(state["coeffs"], shape=self.shape)
+        # the dequantized coefficients are inexact products: the barrier
+        # keeps them from being FMA-contracted into the transform's adds,
+        # which would round differently in a leaf's program and a stacked
+        # one (see core/mgard.py)
+        coeffs = jax.lax.optimization_barrier(state["coeffs"])
+        out = mgard.recompose(coeffs, shape=self.shape)
         return {"data": out.astype(jnp.dtype(self._dtype))}
 
     def stage_meta(self, plan) -> dict:

@@ -119,14 +119,9 @@ def _persisted_race(
     still dethrone it.  A winner outside the current candidate grid (e.g.
     a changed ``c_limit_elems``) is ignored and the spec re-races.
     """
-    try:
-        from ..runtime import calibrate
+    from ..runtime import calibrate
 
-        rec = calibrate.get_race_winner(
-            method, dtype, total_elems, itemsize, backend
-        )
-    except Exception:
-        return None
+    rec = calibrate.get_race_winner(method, dtype, total_elems, itemsize, backend)
     if rec is None:
         return None
     cand = (int(rec["chunk_elems"]), int(rec["window"]))
@@ -144,15 +139,19 @@ def _persisted_race(
 def _persist_winner(
     method, dtype, total_elems, itemsize, backend, ce, w, wall
 ) -> None:
-    """Best-effort: record a converged race winner in the calibration store."""
-    try:
-        from ..runtime import calibrate
+    """Record a converged race winner in the calibration store.
 
+    Persisting only saves a later process its exploration runs, so a store
+    that cannot be written (read-only home) does not fail the stream.
+    """
+    from ..runtime import calibrate
+
+    try:
         calibrate.record_race_winner(
             method, dtype, total_elems, itemsize, backend,
             chunk_elems=ce, window=w, measured_s=wall,
         )
-    except Exception:
+    except OSError:
         pass
 
 
@@ -321,16 +320,13 @@ def plan_stream(
     cal = calibration
     ov = window_overhead_s
     if cal is None and method is not None:
-        try:
-            from ..runtime import calibrate
+        from ..runtime import calibrate
 
-            cal = calibrate.get_method_calibration(
-                method, dtype, backend, measure=measure, params=params
-            )
-            if ov is None:
-                ov = calibrate.window_overhead_s(backend)
-        except Exception:
-            cal = None
+        cal = calibrate.get_method_calibration(
+            method, dtype, backend, measure=measure, params=params
+        )
+        if ov is None:
+            ov = calibrate.window_overhead_s(backend)
     if cal is None:
         return heuristic_plan(
             total_elems, itemsize, chunk_elems=chunk_elems,

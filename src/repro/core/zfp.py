@@ -25,6 +25,7 @@ uint32 words per block.  ``rate`` is bits/value, 1..32.
 
 from __future__ import annotations
 
+import itertools
 import math
 from dataclasses import dataclass
 from functools import partial
@@ -35,7 +36,6 @@ import numpy as np
 
 from . import bitstream as bs
 from .abstractions import pad_to_blocks
-from .machine import block_view, unblock_view
 
 NBMASK = 0xAAAAAAAA  # Python int → inlined literal (Pallas-safe)
 _I32 = jnp.int32
@@ -47,9 +47,8 @@ _U32 = jnp.uint32
 # ---------------------------------------------------------------------------
 
 
-def fwd_lift_vec(v: jax.Array) -> jax.Array:
-    """Forward lift of 4-vectors along the last axis (int32)."""
-    x, y, z, w = v[..., 0], v[..., 1], v[..., 2], v[..., 3]
+def fwd_lift(x, y, z, w):
+    """Forward lift of one 4-vector given as its four components (int32)."""
     x = x + w
     x = x >> 1
     w = w - x
@@ -64,12 +63,11 @@ def fwd_lift_vec(v: jax.Array) -> jax.Array:
     y = y - w
     w = w + (y >> 1)
     y = y - (w >> 1)
-    return jnp.stack([x, y, z, w], axis=-1)
+    return x, y, z, w
 
 
-def inv_lift_vec(v: jax.Array) -> jax.Array:
-    """Inverse lift of 4-vectors along the last axis (int32)."""
-    x, y, z, w = v[..., 0], v[..., 1], v[..., 2], v[..., 3]
+def inv_lift(x, y, z, w):
+    """Inverse of :func:`fwd_lift` (up to the dropped low bits)."""
     y = y + (w >> 1)
     w = w - (y >> 1)
     y = y + w
@@ -84,7 +82,17 @@ def inv_lift_vec(v: jax.Array) -> jax.Array:
     w = w + x
     x = x << 1
     x = x - w
-    return jnp.stack([x, y, z, w], axis=-1)
+    return x, y, z, w
+
+
+def fwd_lift_vec(v: jax.Array) -> jax.Array:
+    """Forward lift of 4-vectors along the last axis (int32)."""
+    return jnp.stack(fwd_lift(*(v[..., i] for i in range(4))), axis=-1)
+
+
+def inv_lift_vec(v: jax.Array) -> jax.Array:
+    """Inverse lift of 4-vectors along the last axis (int32)."""
+    return jnp.stack(inv_lift(*(v[..., i] for i in range(4))), axis=-1)
 
 
 def fwd_transform(block: jax.Array) -> jax.Array:
@@ -144,22 +152,47 @@ def sequency_permutation(dims: int) -> np.ndarray:
 # ---------------------------------------------------------------------------
 
 
+def float_exponent(a: jax.Array) -> jax.Array:
+    """``frexp`` exponent of finite ``a >= 0`` (0 for 0), from the float bits.
+
+    Integer-only, so the XLA and Pallas lowerings agree on every chip,
+    subnormals included: ``a = m·2^e`` with ``0.5 <= m < 1``.
+    """
+    bits = jax.lax.bitcast_convert_type(a.astype(jnp.float32), _I32)
+    biased = (bits >> 23) & 0xFF
+    mant = bits & 0x7FFFFF
+    bitlen = sum(((mant >> k) != 0).astype(_I32) for k in range(23))
+    e = jnp.where(biased > 0, biased - 126, bitlen - 149)
+    return jnp.where(bits == 0, 0, e).astype(_I32)
+
+
+def _pow2(k: jax.Array) -> jax.Array:
+    """Exact float32 2^k for integer ``k`` in [-126, 127], from its bits."""
+    return jax.lax.bitcast_convert_type((k.astype(_I32) + 127) << 23, jnp.float32)
+
+
+def scale_pow2(x: jax.Array, k: jax.Array) -> jax.Array:
+    """``x · 2^k`` for float32 ``x`` and integer ``k`` in [-252, 254].
+
+    Two exact power-of-two factors, each in the normal range, so no factor
+    overflows or flushes where one ``exp2(k)`` would.
+    """
+    h = k.astype(_I32) >> 1
+    return x * _pow2(h) * _pow2(k - h)
+
+
 def block_emax(block: jax.Array) -> jax.Array:
     """Max binary exponent e with |x| < 2^e over the block (0 for all-zero)."""
-    absmax = jnp.max(jnp.abs(block))
-    _, e = jnp.frexp(absmax)  # absmax = m * 2^e, 0.5 <= m < 1
-    return jnp.where(absmax > 0, e, _I32(0)).astype(_I32)
+    return float_exponent(jnp.max(jnp.abs(block)))
 
 
 def to_fixed_point(block: jax.Array, emax: jax.Array) -> jax.Array:
     """float → int32 at scale 2^(30-emax): |q| < 2^30 (2 headroom bits)."""
-    scale = jnp.exp2(30.0 - emax.astype(jnp.float32))
-    return jnp.round(block.astype(jnp.float32) * scale).astype(_I32)
+    return jnp.round(scale_pow2(block.astype(jnp.float32), 30 - emax)).astype(_I32)
 
 
 def from_fixed_point(q: jax.Array, emax: jax.Array, dtype=jnp.float32) -> jax.Array:
-    scale = jnp.exp2(emax.astype(jnp.float32) - 30.0)
-    return (q.astype(jnp.float32) * scale).astype(dtype)
+    return scale_pow2(q.astype(jnp.float32), emax - 30).astype(dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -260,6 +293,40 @@ def _decompress_blocks(
     return jax.vmap(one)(payload, emax)
 
 
+def _block_offsets(dims: int):
+    return itertools.product(range(4), repeat=dims)
+
+
+def field_to_blocks(padded: jax.Array) -> jax.Array:
+    """(nb, 4^d) blocks of a block-aligned field, block-major, C order.
+
+    One strided slice per in-block offset: no intermediate has a 4-wide
+    minor axis, which the TPU's (8, 128) tiling would pad 32× (a 512³
+    field's reshape-transpose blocking needs 16 GiB there).
+    """
+    planes = [padded[tuple(slice(o, None, 4) for o in off)]
+              for off in _block_offsets(padded.ndim)]
+    return jnp.stack(planes).reshape(len(planes), -1).T
+
+
+def blocks_to_field(blocks: jax.Array, padded: tuple[int, ...]) -> jax.Array:
+    """Inverse of :func:`field_to_blocks` for a field of shape ``padded``.
+
+    Scatter-free: each axis is rebuilt from its (count, offset) pair moved
+    to the front, where interleaving is a leading-dims reshape; the minor
+    axis stays a block-count or field axis until the final transpose.
+    """
+    d = len(padded)
+    a = blocks.T.reshape((4,) * d + tuple(p // 4 for p in padded))
+    for axis in range(d):
+        # dims now: (done axes, newest first) + offsets o_axis.. + counts c_axis..
+        done = axis
+        o_pos, c_pos = done, done + (d - axis)
+        a = jnp.moveaxis(a, (c_pos, o_pos), (0, 1))
+        a = a.reshape((a.shape[0] * 4,) + a.shape[2:])
+    return jnp.transpose(a, tuple(range(d - 1, -1, -1)))
+
+
 @partial(jax.jit, static_argnames=("rate", "dims", "shape", "adapter"))
 def compress_jit(
     data: jax.Array, rate: int, dims: int, shape: tuple[int, ...],
@@ -273,17 +340,15 @@ def compress_jit(
     i.e. once per plan.
     """
     block_shape = (4,) * dims
-    padded = pad_to_blocks(data.reshape(shape), block_shape)
-    blocks, _counts = block_view(padded, block_shape)
+    blocks = field_to_blocks(pad_to_blocks(data.reshape(shape), block_shape))
     if adapter is None:
         perm = jnp.asarray(sequency_permutation(dims))
-        return _compress_blocks(blocks, rate, perm)
+        return _compress_blocks(
+            blocks.reshape((-1,) + block_shape), rate, perm
+        )
     from repro.kernels.zfp_block import ops as zfp_block_ops  # lazy: layer order
 
-    nb = blocks.shape[0]
-    return zfp_block_ops.compress_blocks(
-        blocks.reshape(nb, -1), rate, dims, adapter=adapter
-    )
+    return zfp_block_ops.compress_blocks(blocks, rate, dims, adapter=adapter)
 
 
 @partial(jax.jit, static_argnames=("rate", "dims", "shape", "adapter"))
@@ -296,17 +361,16 @@ def decompress_jit(
         perm = sequency_permutation(dims)
         inv_perm = jnp.asarray(np.argsort(perm).astype(np.int32))
         blocks = _decompress_blocks(payload, emax, rate, inv_perm, block_shape)
+        blocks = blocks.reshape(blocks.shape[0], -1)
     else:
         from repro.kernels.zfp_block import ops as zfp_block_ops  # lazy
 
-        flat = zfp_block_ops.decompress_blocks(
+        blocks = zfp_block_ops.decompress_blocks(
             payload, emax, rate, dims, adapter=adapter
         )
-        blocks = flat.reshape((flat.shape[0],) + block_shape)
     from .abstractions import padded_shape
 
-    counts = tuple(p // 4 for p in padded_shape(shape, block_shape))
-    full = unblock_view(blocks, counts, block_shape)
+    full = blocks_to_field(blocks, padded_shape(shape, block_shape))
     return full[tuple(slice(0, d) for d in shape)]
 
 

@@ -29,8 +29,9 @@ def _lerp_kernel(u_ref, mc_ref):
 @functools.partial(jax.jit, static_argnames=("r", "interpret"))
 def lerp_coefficients(
     rows: jax.Array,  # (B, n) float32, n odd
+    *,
+    interpret: bool,
     r: int = DEFAULT_R,
-    interpret: bool = True,
 ) -> jax.Array:
     b, n = rows.shape
     assert n % 2 == 1 and n >= 3, "solve axis must be odd-sized (2m+1)"

@@ -1,10 +1,13 @@
 """Quantize kernel — Pallas TPU (Map&Process stage of MGARD-X).
 
-Fuses per-level bin gather + uniform quantization + zig-zag in one pass over
-the coefficient array: each grid cell stages a tile of coefficients and the
-(tiny) per-level bin table in VMEM.  The inverse kernel fuses the matching
-dequantize.  This is the masked-dense / param-gather lowering of the paper's
-Map&Process abstraction (Fig. 3c).
+Fuses per-level bin selection + uniform quantization + zig-zag in one pass
+over the coefficient array.  The coefficients ride a lane-dense
+``(rows, 128)`` layout; each grid cell stages ``(T, 128)`` of them in VMEM
+and the (tiny) per-level bin table in SMEM.  The bin of each element is a
+compare-and-select over the few levels (the TPU has no vector gather), which
+equals the oracle's table gather for every in-range level id.  The inverse
+kernel fuses the matching dequantize.  This is the masked-dense lowering of
+the paper's Map&Process abstraction (Fig. 3c).
 """
 
 from __future__ import annotations
@@ -13,24 +16,61 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-DEFAULT_T = 65536
+LANES = 128
+DEFAULT_T = 512  # rows of 128 elements per grid cell (256 KiB of f32)
+
+
+def _level_bins(lvl, bins_ref):
+    """Per-element bin by compare-and-select over the level table.
+
+    Level ids clamp into the table like the oracle's gather does.
+    """
+    nl = bins_ref.shape[0]
+    lvl = jnp.clip(lvl, 0, nl - 1)
+    b = jnp.full(lvl.shape, bins_ref[0], jnp.float32)
+    for level in range(1, nl):
+        b = jnp.where(lvl == level, bins_ref[level], b)
+    return b
 
 
 def _quant_kernel(x_ref, lvl_ref, bins_ref, q_ref):
-    x = x_ref[...]
-    bins = jnp.take(bins_ref[...], lvl_ref[...], axis=0)
-    q = jnp.round(x / bins).astype(jnp.int32)
-    q_ref[...] = ((q << 1) ^ (q >> 31)).view(jnp.uint32)  # zig-zag
+    q = jnp.round(x_ref[...] / _level_bins(lvl_ref[...], bins_ref)).astype(jnp.int32)
+    q_ref[...] = (q << 1) ^ (q >> 31)  # zig-zag (bit pattern of the uint32 key)
 
 
 def _dequant_kernel(u_ref, lvl_ref, bins_ref, x_ref):
-    u = u_ref[...].astype(jnp.uint32)
-    q = ((u >> 1).astype(jnp.int32)) ^ -(u & np.uint32(1)).astype(jnp.int32)
-    bins = jnp.take(bins_ref[...], lvl_ref[...], axis=0)
-    x_ref[...] = q.astype(jnp.float32) * bins
+    u = u_ref[...]  # zig-zagged keys, carried as int32 bit patterns
+    q = jax.lax.shift_right_logical(u, 1) ^ -(u & 1)
+    x_ref[...] = q.astype(jnp.float32) * _level_bins(lvl_ref[...], bins_ref)
+
+
+def _lane_dense(n: int, t: int) -> tuple[int, int]:
+    """(rows per tile, padded rows) for ``n`` elements in (rows, 128) tiles."""
+    rows = -(-n // LANES)
+    tile = min(t, -(-rows // 8) * 8)
+    return tile, -(-rows // tile) * tile
+
+
+def _map_call(kernel, a, levels, bins, out_dtype, t, interpret):
+    n = a.shape[0]
+    tile, rows = _lane_dense(n, t)
+    pad = rows * LANES - n
+    a = jnp.pad(a, (0, pad)).reshape(rows, LANES)
+    levels = jnp.pad(levels, (0, pad)).reshape(rows, LANES)
+    block = pl.BlockSpec((tile, LANES), lambda i: (i, 0))
+    out = pl.pallas_call(
+        kernel,
+        grid=(rows // tile,),
+        in_specs=[block, block, pl.BlockSpec(memory_space=pltpu.SMEM)],
+        out_specs=block,
+        out_shape=jax.ShapeDtypeStruct((rows, LANES), out_dtype),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
+        interpret=interpret,
+    )(a, levels, bins.astype(jnp.float32))
+    return out.reshape(-1)[:n]
 
 
 @functools.partial(jax.jit, static_argnames=("t", "interpret"))
@@ -38,58 +78,28 @@ def quantize(
     x: jax.Array,        # (N,) float32 coefficients
     levels: jax.Array,   # (N,) int32 subset ids
     bins: jax.Array,     # (L+1,) float32
+    *,
+    interpret: bool,
     t: int = DEFAULT_T,
-    interpret: bool = True,
 ) -> jax.Array:
-    x = x.reshape(-1).astype(jnp.float32)
-    levels = levels.reshape(-1).astype(jnp.int32)
-    n = x.shape[0]
-    n_pad = (-n) % t
-    if n_pad:
-        x = jnp.pad(x, (0, n_pad))
-        levels = jnp.pad(levels, (0, n_pad))
-    nl = bins.shape[0]
-    out = pl.pallas_call(
-        _quant_kernel,
-        grid=(x.shape[0] // t,),
-        in_specs=[
-            pl.BlockSpec((t,), lambda i: (i,)),
-            pl.BlockSpec((t,), lambda i: (i,)),
-            pl.BlockSpec((nl,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((t,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((x.shape[0],), jnp.uint32),
-        interpret=interpret,
-    )(x, levels, bins.astype(jnp.float32))
-    return out[:n]
+    q = _map_call(
+        _quant_kernel, x.reshape(-1).astype(jnp.float32),
+        levels.reshape(-1).astype(jnp.int32), bins, jnp.int32, t, interpret,
+    )
+    return jax.lax.bitcast_convert_type(q, jnp.uint32)
 
 
 @functools.partial(jax.jit, static_argnames=("t", "interpret"))
 def dequantize(
-    u: jax.Array,
+    u: jax.Array,        # (N,) uint32 zig-zagged keys
     levels: jax.Array,
     bins: jax.Array,
+    *,
+    interpret: bool,
     t: int = DEFAULT_T,
-    interpret: bool = True,
 ) -> jax.Array:
-    u = u.reshape(-1).astype(jnp.uint32)
-    levels = levels.reshape(-1).astype(jnp.int32)
-    n = u.shape[0]
-    n_pad = (-n) % t
-    if n_pad:
-        u = jnp.pad(u, (0, n_pad))
-        levels = jnp.pad(levels, (0, n_pad))
-    nl = bins.shape[0]
-    out = pl.pallas_call(
-        _dequant_kernel,
-        grid=(u.shape[0] // t,),
-        in_specs=[
-            pl.BlockSpec((t,), lambda i: (i,)),
-            pl.BlockSpec((t,), lambda i: (i,)),
-            pl.BlockSpec((nl,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((t,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((u.shape[0],), jnp.float32),
-        interpret=interpret,
-    )(u, levels, bins.astype(jnp.float32))
-    return out[:n]
+    u = jax.lax.bitcast_convert_type(u.reshape(-1).astype(jnp.uint32), jnp.int32)
+    return _map_call(
+        _dequant_kernel, u, levels.reshape(-1).astype(jnp.int32), bins,
+        jnp.float32, t, interpret,
+    )

@@ -48,8 +48,9 @@ def _tridiag_kernel(rhs_ref, cp_ref, dinv_ref, x_ref, *, sub):
 def solve_mass(
     rhs: jax.Array,  # (N, n) float32 — N independent systems
     h: float,
+    *,
+    interpret: bool,
     b: int = DEFAULT_B,
-    interpret: bool = True,
 ) -> jax.Array:
     nsys, n = rhs.shape
     cp_np, dinv_np = _thomas_coeffs(n, h)

@@ -1,17 +1,17 @@
 """ZFP-X block kernel — Pallas TPU implementation (GEM lowering).
 
-One grid cell processes ``TB`` 4^d blocks staged in VMEM (the paper's
-block→SM mapping becomes block-batch→grid-cell: TPU grid cells consume whole
-tiles, so we batch blocks to fill the 8×128 VPU registers).  All five stages
-(exponent align → lift → negabinary → permute → bitplane pack) run fused in
-VMEM — the multi-stage GEM execution of Table I/II.
-
-Layout: ``(TB, block_size)`` with TB a multiple of 8 sublanes; the
-block-coefficient axis rides the 128-wide lane dimension.  The sequency
-permutation is passed as a (replicated) VMEM operand — the same pattern GPU
-kernels use for constant tables in shared memory.  Matmul-free: this kernel
-is VPU (shift/add) bound, which is why ZFP is the highest-throughput
-pipeline on every backend (paper Fig. 12).
+One grid cell processes ``TR × 128`` 4^d blocks staged in VMEM (the paper's
+block→SM mapping becomes block-batch→grid-cell).  The blocks ride the
+lanes: the wrapper lays the batch out coefficient-major as
+``(4^d, blocks/128, 128)``, so every block coefficient is one full
+``(TR, 128)`` vector and all five stages (exponent align → lift →
+negabinary → permute → bitplane pack) are elementwise vector ops fused in
+VMEM — the multi-stage GEM execution of Table I/II.  The lifting partners
+and the sequency permutation are static coefficient indices, so the kernel
+needs no gather and no lane shuffle; the bitplane packing ORs disjoint
+shifted bits into int32 words, which is the oracle's unsigned sum bit for
+bit.  Matmul-free: this kernel is VPU (shift/add) bound, which is why ZFP
+is the highest-throughput pipeline on every backend (paper Fig. 12).
 """
 
 from __future__ import annotations
@@ -22,123 +22,164 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import zfp as core_zfp
 
-DEFAULT_TB = 256  # blocks per grid cell
+LANES = 128
+DEFAULT_TR = 8  # rows of 128 blocks per grid cell
+_NB = int(np.int32(np.uint32(core_zfp.NBMASK).view(np.int32)))  # as int32
 
 
-def _compress_tile(blocks_f32: jax.Array, perm: jax.Array, rate: int, dims: int):
-    """(TB, 4^dims) float32 → ((TB, wpb) uint32, (TB,) int32). Pure jnp on VMEM."""
-    tb, bs = blocks_f32.shape
-    shaped = blocks_f32.reshape((tb,) + (4,) * dims)
-    absmax = jnp.max(jnp.abs(blocks_f32), axis=1)
-    _, e = jnp.frexp(absmax)
-    emax = jnp.where(absmax > 0, e, 0).astype(jnp.int32)
-    scale = jnp.exp2(30.0 - emax.astype(jnp.float32))
-    q = jnp.round(shaped * scale.reshape((tb,) + (1,) * dims)).astype(jnp.int32)
-    t = q
-    for axis in range(1, dims + 1):
-        moved = jnp.moveaxis(t, axis, -1)
-        moved = core_zfp.fwd_lift_vec(moved)
-        t = jnp.moveaxis(moved, -1, axis)
-    u = core_zfp.int_to_negabinary(t.reshape(tb, bs))
-    u = jnp.take(u, perm, axis=1)
-    payload = core_zfp.pack_bitplanes(u, rate)
-    return payload, emax
+def _lift_axis(t, dims: int, axis: int, lift):
+    """Apply ``lift`` to every 4-vector of ``t`` (4^d, TR, 128) along ``axis``.
+
+    Coefficients are in C order over ``(4,)*dims``, so the four components
+    along ``axis`` are index slices of a leading-dims reshape.
+    """
+    outer, inner = 4 ** axis, 4 ** (dims - 1 - axis)
+    tail = t.shape[1:]
+    v = t.reshape((outer, 4, inner) + tail)
+    parts = lift(*(v[:, k] for k in range(4)))
+    return jnp.stack(parts, axis=1).reshape(t.shape)
 
 
-def _decompress_tile(
-    payload: jax.Array, emax: jax.Array, inv_perm: jax.Array, rate: int, dims: int
-):
-    tb = payload.shape[0]
+def _word_segments(bs: int, rate: int):
+    """For each packed word, its runs ``(plane, j0, i0, m)``: coefficients
+    ``j0..j0+m`` (sequency order) of ``plane`` at word bits ``i0..i0+m``
+    (MSB-first)."""
+    words = []
+    for g in range(-(-rate * bs // 32)):
+        runs = []
+        f = 32 * g
+        while f < min(32 * g + 32, rate * bs):
+            p, j = divmod(f, bs)
+            m = min(bs - j, 32 * g + 32 - f)
+            runs.append((p, j, f - 32 * g, m))
+            f += m
+        words.append(runs)
+    return words
+
+
+def _msb_shifts(i0: int, m: int, tail):
+    """(m, *tail) int32 of ``31 - i`` for word bits ``i = i0..i0+m``."""
+    return (31 - i0) - jax.lax.broadcasted_iota(jnp.int32, (m,) + tail, 0)
+
+
+def _compress_kernel(x_ref, payload_ref, emax_ref, *, rate, dims):
     bs = 4 ** dims
-    u = core_zfp.unpack_bitplanes(payload, rate, bs)
-    u = jnp.take(u, inv_perm, axis=1)
-    t = core_zfp.negabinary_to_int(u).reshape((tb,) + (4,) * dims)
-    for axis in range(dims, 0, -1):
-        moved = jnp.moveaxis(t, axis, -1)
-        moved = core_zfp.inv_lift_vec(moved)
-        t = jnp.moveaxis(moved, -1, axis)
-    scale = jnp.exp2(emax.astype(jnp.float32) - 30.0)
-    return t.reshape(tb, bs).astype(jnp.float32) * scale[:, None]
-
-
-def _compress_kernel(x_ref, perm_ref, payload_ref, emax_ref, *, rate, dims):
-    payload, emax = _compress_tile(x_ref[...], perm_ref[...], rate, dims)
-    payload_ref[...] = payload
+    x = x_ref[...]                                   # (4^d, TR, 128)
+    tail = x.shape[1:]
+    emax = core_zfp.float_exponent(jnp.max(jnp.abs(x), axis=0))
+    t = core_zfp.to_fixed_point(x, emax[None])
+    for a in range(dims):
+        t = _lift_axis(t, dims, a, core_zfp.fwd_lift)
+    perm = core_zfp.sequency_permutation(dims)
+    u = (t + _NB) ^ _NB                              # negabinary
+    u = jnp.concatenate([u[int(c):int(c) + 1] for c in perm])  # sequency order
+    for g, runs in enumerate(_word_segments(bs, rate)):
+        word = None
+        for p, j0, i0, m in runs:
+            bits = jax.lax.shift_right_logical(u[j0:j0 + m], 31 - p) & 1
+            part = jnp.sum(bits << _msb_shifts(i0, m, tail), axis=0)  # OR
+            word = part if word is None else word | part
+        payload_ref[g] = word
     emax_ref[...] = emax
 
 
-def _decompress_kernel(p_ref, e_ref, iperm_ref, out_ref, *, rate, dims):
-    out_ref[...] = _decompress_tile(p_ref[...], e_ref[...], iperm_ref[...], rate, dims)
+def _decompress_kernel(p_ref, e_ref, out_ref, *, rate, dims):
+    bs = 4 ** dims
+    tail = e_ref.shape
+    planes = {}
+    for g, runs in enumerate(_word_segments(bs, rate)):
+        w = p_ref[g][None]
+        for p, j0, i0, m in runs:
+            bits = jax.lax.shift_right_logical(w, _msb_shifts(i0, m, tail)) & 1
+            planes.setdefault(p, []).append(bits << (31 - p))
+    u = None
+    for p in range(rate):
+        plane = jnp.concatenate(planes[p]) if len(planes[p]) > 1 else planes[p][0]
+        u = plane if u is None else u | plane       # (4^d, TR, 128)
+    inv = np.argsort(core_zfp.sequency_permutation(dims))
+    u = jnp.concatenate([u[int(j):int(j) + 1] for j in inv])  # natural order
+    t = (u ^ _NB) - _NB                              # negabinary → int
+    for a in reversed(range(dims)):
+        t = _lift_axis(t, dims, a, core_zfp.inv_lift)
+    out_ref[...] = core_zfp.from_fixed_point(t, e_ref[...][None])
 
 
-@functools.partial(jax.jit, static_argnames=("rate", "dims", "tb", "interpret"))
+def _lanes_tiles(n: int, tr: int) -> tuple[int, int]:
+    rows = max(1, -(-n // LANES))
+    tr = min(tr, -(-rows // 8) * 8)
+    return tr, -(-rows // tr) * tr
+
+
+def _params():
+    return pltpu.CompilerParams(dimension_semantics=("parallel",))
+
+
+@functools.partial(jax.jit, static_argnames=("rate", "dims", "tr", "interpret"))
 def compress_blocks(
     blocks: jax.Array,  # (N, 4^dims) float32
     rate: int,
     dims: int,
-    tb: int = DEFAULT_TB,
-    interpret: bool = True,
+    *,
+    interpret: bool,
+    tr: int = DEFAULT_TR,
 ) -> tuple[jax.Array, jax.Array]:
     n, bs = blocks.shape
-    assert bs == 4 ** dims
+    if bs != 4 ** dims:
+        raise ValueError(f"blocks of {bs} values are not 4^{dims}")
     wpb = core_zfp.words_per_block(bs, rate)
-    n_pad = (-n) % tb
-    if n_pad:
-        blocks = jnp.pad(blocks, ((0, n_pad), (0, 0)))
-    n_t = blocks.shape[0]
-    perm = jnp.asarray(core_zfp.sequency_permutation(dims))
+    tr, rows = _lanes_tiles(n, tr)
+    x = jnp.pad(blocks.astype(jnp.float32), ((0, rows * LANES - n), (0, 0)))
+    x = x.T.reshape(bs, rows, LANES)
     payload, emax = pl.pallas_call(
         functools.partial(_compress_kernel, rate=rate, dims=dims),
-        grid=(n_t // tb,),
-        in_specs=[
-            pl.BlockSpec((tb, bs), lambda i: (i, 0)),
-            pl.BlockSpec((bs,), lambda i: (0,)),  # replicated table (VMEM-staged)
-        ],
+        grid=(rows // tr,),
+        in_specs=[pl.BlockSpec((bs, tr, LANES), lambda i: (0, i, 0))],
         out_specs=(
-            pl.BlockSpec((tb, wpb), lambda i: (i, 0)),
-            pl.BlockSpec((tb,), lambda i: (i,)),
+            pl.BlockSpec((wpb, tr, LANES), lambda i: (0, i, 0)),
+            pl.BlockSpec((tr, LANES), lambda i: (i, 0)),
         ),
         out_shape=(
-            jax.ShapeDtypeStruct((n_t, wpb), jnp.uint32),
-            jax.ShapeDtypeStruct((n_t,), jnp.int32),
+            jax.ShapeDtypeStruct((wpb, rows, LANES), jnp.int32),
+            jax.ShapeDtypeStruct((rows, LANES), jnp.int32),
         ),
+        compiler_params=_params(),
         interpret=interpret,
-    )(blocks, perm)
-    return payload[:n], emax[:n]
+    )(x)
+    payload = payload.reshape(wpb, rows * LANES).T[:n]
+    return jax.lax.bitcast_convert_type(payload, jnp.uint32), emax.reshape(-1)[:n]
 
 
-@functools.partial(jax.jit, static_argnames=("rate", "dims", "tb", "interpret"))
+@functools.partial(jax.jit, static_argnames=("rate", "dims", "tr", "interpret"))
 def decompress_blocks(
     payload: jax.Array,  # (N, wpb) uint32
     emax: jax.Array,     # (N,) int32
     rate: int,
     dims: int,
-    tb: int = DEFAULT_TB,
-    interpret: bool = True,
+    *,
+    interpret: bool,
+    tr: int = DEFAULT_TR,
 ) -> jax.Array:
     n, wpb = payload.shape
     bs = 4 ** dims
-    n_pad = (-n) % tb
-    if n_pad:
-        payload = jnp.pad(payload, ((0, n_pad), (0, 0)))
-        emax = jnp.pad(emax, (0, n_pad))
-    n_t = payload.shape[0]
-    inv_perm = jnp.asarray(
-        np.argsort(core_zfp.sequency_permutation(dims)).astype(np.int32)
-    )
+    tr, rows = _lanes_tiles(n, tr)
+    pad = rows * LANES - n
+    p = jax.lax.bitcast_convert_type(payload.astype(jnp.uint32), jnp.int32)
+    p = jnp.pad(p, ((0, pad), (0, 0))).T.reshape(wpb, rows, LANES)
+    e = jnp.pad(emax.astype(jnp.int32), (0, pad)).reshape(rows, LANES)
     out = pl.pallas_call(
         functools.partial(_decompress_kernel, rate=rate, dims=dims),
-        grid=(n_t // tb,),
+        grid=(rows // tr,),
         in_specs=[
-            pl.BlockSpec((tb, wpb), lambda i: (i, 0)),
-            pl.BlockSpec((tb,), lambda i: (i,)),
-            pl.BlockSpec((bs,), lambda i: (0,)),
+            pl.BlockSpec((wpb, tr, LANES), lambda i: (0, i, 0)),
+            pl.BlockSpec((tr, LANES), lambda i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((tb, bs), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_t, bs), jnp.float32),
+        out_specs=pl.BlockSpec((bs, tr, LANES), lambda i: (0, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((bs, rows, LANES), jnp.float32),
+        compiler_params=_params(),
         interpret=interpret,
-    )(payload, emax, inv_perm)
-    return out[:n]
+    )(p, e)
+    return out.reshape(bs, rows * LANES).T[:n]

@@ -113,9 +113,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: Path,
         counts = roofline.count_params(params_shape)
         record["param_counts"] = counts
 
-        from repro.launch.mesh import use_mesh
-
-        with use_mesh(mesh):  # ambient mesh: activation constraints resolve
+        with jax.set_mesh(mesh):  # ambient mesh: activation constraints resolve
             if shape.kind == "train":
                 # opt variant for FSDP giants: bf16 moments (memory-roofline lever)
                 moment_dtype = "bfloat16" if (variant == "opt" and cfg.fsdp) else "float32"
@@ -172,17 +170,19 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: Path,
         # counts while bodies once — raw kept alongside for transparency),
         # memory = max(HLO bytes, analytic traffic), collective = scaled HLO.
         hlo_bytes_dev = record["cost"].get("bytes accessed", 0.0)
+        pk = roofline.peaks(roofline.V5E_KIND)  # the production mesh's chip
         terms = roofline.RooflineTerms(
-            t_compute=(mf["model_flops"] / chips) / roofline.PEAK_FLOPS,
-            t_memory=max(hlo_bytes_dev, analytic_mem) / roofline.HBM_BW,
-            t_collective=coll.total_link_bytes / roofline.ICI_BW,
+            t_compute=(mf["model_flops"] / chips) / pk.bf16_flops,
+            t_memory=max(hlo_bytes_dev, analytic_mem) / pk.hbm_bw,
+            t_collective=coll.total_link_bytes / pk.ici_bw,
             flops=mf["model_flops"] / chips,
             bytes_accessed=max(hlo_bytes_dev, analytic_mem),
             link_bytes=coll.total_link_bytes,
         )
         record["roofline"] = terms.to_dict()
         record["roofline_raw_hlo"] = roofline.terms_from_analysis(
-            record["cost"], record["collectives_raw"]["total_link_bytes"]
+            record["cost"], record["collectives_raw"]["total_link_bytes"],
+            device_kind=roofline.V5E_KIND,
         ).to_dict()
         hlo_flops_global = record["cost"].get("flops", 0.0) * chips
         record["useful_flops_ratio_vs_raw_hlo"] = (

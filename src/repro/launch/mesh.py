@@ -16,35 +16,11 @@ from pathlib import Path
 import jax
 
 
-def _axis_type_kwargs(n: int) -> dict:
-    """``axis_types`` keyword for ``jax.make_mesh``, when this JAX has it.
-
-    ``jax.sharding.AxisType`` (and the matching ``axis_types=`` parameter)
-    only exist on newer JAX; on 0.4.x every mesh axis is implicitly Auto, so
-    omitting the keyword is behaviour-identical.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
-
-
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """Version-portable ``jax.make_mesh`` with Auto axis types."""
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
-
-
-def use_mesh(mesh):
-    """Context manager that activates ``mesh`` as ambient default.
-
-    Newer JAX spells this ``jax.set_mesh``; on 0.4.x the ``Mesh`` object is
-    its own context manager with the same effect for jit/pjit name
-    resolution.
-    """
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
+    """``jax.make_mesh`` with every axis explicitly Auto."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -126,10 +102,7 @@ def detect_topology() -> HostTopology:
     env_n = os.environ.get(ENV_HOST_COUNT)
     if env_n is not None:
         return HostTopology(int(os.environ.get(ENV_HOST_ID, 0)), int(env_n))
-    try:
-        return HostTopology(jax.process_index(), jax.process_count())
-    except Exception:
-        return HostTopology(0, 1)
+    return HostTopology(jax.process_index(), jax.process_count())
 
 
 def fs_barrier(

@@ -236,7 +236,6 @@ def moe_layer_a2a(
     by the device count; returns None to fall back to the einsum path
     otherwise (CPU tests, decode micro-batches).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as _P
 
     m = cfg.moe
@@ -313,7 +312,7 @@ def moe_layer_a2a(
     flat_spec = _P(all_axes)
     expert_spec = _P(a2a_axes, None, None)
     rep = _P()
-    out = shard_map(
+    out = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(
@@ -321,7 +320,7 @@ def moe_layer_a2a(
             _P(a2a_axes, None, None), jax.tree.map(lambda _: rep, shared),
         ),
         out_specs=(_P(all_axes, None), rep),
-        check_rep=False,
+        check_vma=False,
     )(x.reshape(t, d), p["router"], p["wg"], p["wu"], p["wd"], shared)
     y, aux = out
     return y.reshape(b, s, d), aux

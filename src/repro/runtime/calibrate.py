@@ -87,12 +87,10 @@ def set_calibration_dir(path: str | Path | None) -> None:
     with _LOCK:
         _DIR_OVERRIDE = str(path) if path is not None else None
         _STORES.clear()
-    try:  # solved plans / residuals derive from the old store: drop them
-        from ..core import tuner as _tuner
+    # solved plans / residuals derive from the old store: drop them
+    from ..core import tuner as _tuner
 
-        _tuner.clear_caches()
-    except Exception:
-        pass
+    _tuner.clear_caches()
 
 
 def calibration_dir() -> Path:
@@ -469,29 +467,26 @@ class Calibrator:
                 h2d.time_for, phi.time_for, ser.time_for, window=window)
             return mk
 
-        try:
-            tiny = int(self.sweep_elems[0])
-            stream_t0 = max(0.0, wall(1, tiny, 1) - sim(1, tiny, 1))
+        tiny = int(self.sweep_elems[0])
+        stream_t0 = max(0.0, wall(1, tiny, 1) - sim(1, tiny, 1))
 
-            big = int(self.sweep_elems[-1])
-            serial_wall = wall(1, big, n_probe)
-            chunk_t0 = max(
-                0.0,
-                (serial_wall - sim(1, big, n_probe) - stream_t0) / n_probe,
-            )
-            fixed = stream_t0 + n_probe * chunk_t0
+        big = int(self.sweep_elems[-1])
+        serial_wall = wall(1, big, n_probe)
+        chunk_t0 = max(
+            0.0,
+            (serial_wall - sim(1, big, n_probe) - stream_t0) / n_probe,
+        )
+        fixed = stream_t0 + n_probe * chunk_t0
 
-            def scale(measured: float, window: int) -> float:
-                predicted = sim(window, big, n_probe) + fixed
-                if not (np.isfinite(measured) and np.isfinite(predicted)) \
-                        or predicted <= 0:
-                    return 1.0
-                return float(np.clip(measured / predicted, 0.2, 50.0))
+        def scale(measured: float, window: int) -> float:
+            predicted = sim(window, big, n_probe) + fixed
+            if not (np.isfinite(measured) and np.isfinite(predicted)) \
+                    or predicted <= 0:
+                return 1.0
+            return float(np.clip(measured / predicted, 0.2, 50.0))
 
-            return (stream_t0, chunk_t0, scale(serial_wall, 1),
-                    scale(wall(2, big, n_probe), 2))
-        except Exception:
-            return 0.0, 0.0, 1.0, 1.0
+        return (stream_t0, chunk_t0, scale(serial_wall, 1),
+                scale(wall(2, big, n_probe), 2))
 
     @staticmethod
     def _encode_once(codec, plan, dev):

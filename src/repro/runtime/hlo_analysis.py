@@ -222,13 +222,6 @@ def parse_collectives_scaled(hlo_text: str) -> HloCollectives:
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """``Compiled.cost_analysis()`` normalized across JAX versions.
-
-    JAX 0.4.x returns a one-element list of per-program dicts; newer JAX
-    returns the dict directly.  Either way the result here is a plain dict
-    (empty when XLA reports nothing).
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost) if cost else {}
+    """``Compiled.cost_analysis()`` as a plain dict (empty when XLA reports
+    nothing)."""
+    return dict(compiled.cost_analysis() or {})

@@ -1,4 +1,4 @@
-"""Roofline model — TPU v5e-like hardware constants + the three terms.
+"""Roofline model — per-device peaks + the three terms.
 
     compute    = HLO_FLOPs_per_device / peak_FLOP/s
     memory     = HLO_bytes_per_device / HBM_bw
@@ -30,10 +30,38 @@ import numpy as np
 
 from ..configs.base import ModelConfig, ShapeConfig
 
-PEAK_FLOPS = 197e12     # bf16 / chip
-HBM_BW = 819e9          # B/s / chip
-ICI_BW = 50e9           # B/s / link
-HBM_PER_CHIP = 16e9     # v5e
+
+
+@dataclass(frozen=True)
+class DevicePeaks:
+    """Published per-chip peaks of one device kind."""
+
+    bf16_flops: float   # FLOP/s
+    hbm_bw: float       # B/s
+    hbm_bytes: float    # B
+    ici_bw: float       # B/s per link
+
+
+# Keyed by ``jax.Device.device_kind``.  TPU v5e: Google Cloud documentation,
+# "TPU v5e" (197 TFLOP/s bf16, 16 GB HBM at 819 GB/s).
+_V5E = DevicePeaks(bf16_flops=197e12, hbm_bw=819e9, hbm_bytes=16e9, ici_bw=50e9)
+PEAKS: dict[str, DevicePeaks] = {"TPU v5 lite": _V5E, "TPU v5e": _V5E}
+V5E_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str | None = None) -> DevicePeaks:
+    """Peaks of ``device_kind`` (default: the first local device's kind).
+
+    A kind the table does not know raises: a roofline against another
+    chip's peaks would be a number under the wrong device's name.
+    """
+    kind = jax.devices()[0].device_kind if device_kind is None else device_kind
+    try:
+        return PEAKS[kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {kind!r}; known: {sorted(PEAKS)}"
+        ) from None
 
 
 @dataclass
@@ -71,14 +99,16 @@ class RooflineTerms:
 
 
 def terms_from_analysis(
-    cost: dict | None, link_bytes: float, flops_override: float | None = None
+    cost: dict | None, link_bytes: float, flops_override: float | None = None,
+    *, device_kind: str | None = None,
 ) -> RooflineTerms:
+    pk = peaks(device_kind)
     flops = float(flops_override if flops_override is not None else (cost or {}).get("flops", 0.0))
     nbytes = float((cost or {}).get("bytes accessed", 0.0))
     return RooflineTerms(
-        t_compute=flops / PEAK_FLOPS,
-        t_memory=nbytes / HBM_BW,
-        t_collective=link_bytes / ICI_BW,
+        t_compute=flops / pk.bf16_flops,
+        t_memory=nbytes / pk.hbm_bw,
+        t_collective=link_bytes / pk.ici_bw,
         flops=flops,
         bytes_accessed=nbytes,
         link_bytes=link_bytes,

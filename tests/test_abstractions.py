@@ -68,8 +68,26 @@ def test_adapter_registry_dispatch():
     assert adapters.resolve("auto") in adapters.ADAPTERS
     with pytest.raises(ValueError):
         adapters.resolve("cuda")
-    # registered kernel ops fall back to xla when pallas impl missing
     fn = adapters.dispatch("histogram", "xla")
     assert callable(fn)
+    assert adapters.lowering("histogram", "pallas_interpret") == "pallas_interpret"
+    # only an op declared XLA-only falls back to its xla implementation
+    assert "huffman_pack_stream" in adapters.XLA_ONLY_OPS
+    assert adapters.lowering("huffman_pack_stream", "pallas_interpret") == "xla"
+    assert (adapters.dispatch("huffman_pack_stream", "pallas_interpret")
+            is adapters.dispatch("huffman_pack_stream", "xla"))
     with pytest.raises(KeyError):
         adapters.dispatch("nonexistent_op", "xla")
+
+
+def test_dispatch_refuses_undeclared_xla_fallback(monkeypatch):
+    """An op with only an xla implementation raises under a kernel adapter
+    unless it is declared XLA-only."""
+    monkeypatch.setitem(adapters._REGISTRY, ("_probe_op", adapters.XLA), len)
+    assert adapters.dispatch("_probe_op", "xla") is len
+    with pytest.raises(KeyError, match="_probe_op"):
+        adapters.dispatch("_probe_op", "pallas_interpret")
+    monkeypatch.setattr(
+        adapters, "XLA_ONLY_OPS", adapters.XLA_ONLY_OPS | {"_probe_op"}
+    )
+    assert adapters.dispatch("_probe_op", "pallas_interpret") is len

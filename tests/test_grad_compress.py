@@ -64,8 +64,6 @@ def test_ef_sgd_converges(rng):
 
 def test_pod_compressed_mean_shardmap():
     """pod_compressed_mean inside shard_map equals the true mean (±quant err)."""
-    import jax.experimental.shard_map as shard_map
-
     n_dev = len(jax.devices())
     if n_dev < 2:
         import pytest
@@ -81,7 +79,7 @@ def test_pod_compressed_mean_shardmap():
     def f(local):
         return gc.pod_compressed_mean(local[0], axis_name="pod")
 
-    out = shard_map.shard_map(
+    out = jax.shard_map(
         f, mesh=mesh, in_specs=P("pod", None), out_specs=P(None)
     )(g)
     true = np.asarray(g).mean(0)

@@ -92,3 +92,50 @@ def test_tridiag_kernel(n, h, rng):
             m[i, i + 1] = h / 6
     xd = np.linalg.solve(m, rhs[0])
     np.testing.assert_allclose(x_k[0], xd, rtol=2e-3, atol=2e-4)
+
+
+@pytest.mark.parametrize("nk,chunk", [(50, 1024), (4096, 4096)])
+def test_huffman_decode_kernel(nk, chunk, rng):
+    from repro.core import huffman
+    from repro.kernels.huffman_decode import ops as dec_ops
+
+    keys = (rng.zipf(1.5, 20000) % nk).astype(np.int32)
+    freq = np.bincount(keys, minlength=nk)
+    book = huffman.build_codebook(freq)
+    codes, lens = enc_ops.encode_lookup(
+        jnp.asarray(keys), jnp.asarray(book.codes), jnp.asarray(book.lengths),
+        adapter="xla",
+    )
+    total = int(np.sum(freq * book.lengths))
+    words, offs, _ = enc_ops.pack_stream(codes, lens, total // 32 + 1, chunk, adapter="xla")
+    t = huffman.decode_tables(np.asarray(book.lengths, np.int32))
+    args = (words, offs, jnp.asarray(t.first_code), jnp.asarray(t.count),
+            jnp.asarray(t.sym_offset), jnp.asarray(t.sym_sorted), chunk, t.max_len)
+    d_k = np.asarray(dec_ops.decode_chunks(*args, adapter="pallas_interpret"))
+    d_r = np.asarray(dec_ops.decode_chunks(*args, adapter="xla"))
+    np.testing.assert_array_equal(d_k, d_r)  # padding symbols included
+    np.testing.assert_array_equal(d_k.reshape(-1)[: keys.size], keys)
+
+
+@pytest.mark.parametrize("name", [
+    "histogram", "encode_lookup", "decode_chunks", "quantize", "dequantize",
+    "compress_blocks", "decompress_blocks", "lerp_coefficients", "solve_mass",
+])
+def test_kernel_wrappers_require_interpret(name):
+    """A direct caller must choose: no kernel defaults to the interpreter."""
+    import inspect
+
+    from repro.kernels.histogram import kernel as hk
+    from repro.kernels.huffman_decode import kernel as dk
+    from repro.kernels.huffman_encode import kernel as ek
+    from repro.kernels.mgard_lerp import kernel as lk
+    from repro.kernels.quantize_map import kernel as qk
+    from repro.kernels.tridiag import kernel as tk
+    from repro.kernels.zfp_block import kernel as zk
+
+    mods = {"histogram": hk, "encode_lookup": ek, "decode_chunks": dk,
+            "quantize": qk, "dequantize": qk, "compress_blocks": zk,
+            "decompress_blocks": zk, "lerp_coefficients": lk, "solve_mass": tk}
+    param = inspect.signature(getattr(mods[name], name)).parameters["interpret"]
+    assert param.kind is inspect.Parameter.KEYWORD_ONLY
+    assert param.default is inspect.Parameter.empty

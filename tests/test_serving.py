@@ -302,10 +302,15 @@ def test_service_coalesces_across_requests_with_cmm_hits():
     from repro.core.engine import ExecutionEngine
     from repro.serving import ReductionService
 
+    from repro.core import api
+
     rng = np.random.default_rng(0)
-    # a shape this test owns: plan build below is the only CMM miss for it
     trees = [{"w": rng.normal(size=(37, 53)).astype(np.float32)}
              for _ in range(5)]
+    # leaf_policy re-blocks ZFP leaves to (n, 32, 32), so other tests share
+    # this bucket's plan: drop it, and the build below is the only CMM miss
+    x, method, params = api.leaf_policy(trees[0]["w"], *_zfp_select("w", None))
+    GLOBAL_CMM.discard(api.make_spec(x, method, backend="xla", **params).key())
     with ExecutionEngine(backend="xla") as eng:
         with ReductionService(eng, batch_window=0.05, max_queue=16) as svc:
             misses0 = GLOBAL_CMM.miss_count

@@ -122,9 +122,7 @@ def test_policy_fsdp_dp_and_zero1_compile():
                       param_dtype="bfloat16")
         mesh = make_test_mesh(1, 1)
         model = build_model(cfg)
-        from repro.launch.mesh import use_mesh
-
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             param_sds = S.param_specs(model, mesh)
             opt_cfg = adamw.AdamWConfig()
             opt_sds = S.opt_state_specs(param_sds, mesh, opt_cfg, cfg)

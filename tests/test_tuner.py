@@ -665,3 +665,17 @@ def test_checkpoint_default_policy_streams_nothing_small(cal_dir, tmp_path):
     mgr = CheckpointManager(tmp_path / "ckpt")
     manifest = mgr.save(0, tree)
     assert manifest["leaves"]["w"].get("stream") is None
+
+
+def test_roofline_peaks_keyed_by_device_kind():
+    v5e = roofline.peaks("TPU v5 lite")
+    assert (v5e.bf16_flops, v5e.hbm_bw, v5e.hbm_bytes) == (197e12, 819e9, 16e9)
+    assert roofline.peaks("TPU v5e") == v5e
+    with pytest.raises(ValueError, match="no published peaks"):
+        roofline.peaks("TPU v9 imaginary")
+    with pytest.raises(ValueError, match="cpu"):  # this host's device
+        roofline.peaks()
+    t = roofline.terms_from_analysis(
+        {"flops": 197e12, "bytes accessed": 819e9}, 0.0, device_kind="TPU v5 lite"
+    )
+    assert t.t_compute == pytest.approx(1.0) and t.t_memory == pytest.approx(1.0)
