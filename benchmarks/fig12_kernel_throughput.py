@@ -22,7 +22,7 @@ _TPU_TRAFFIC_FACTOR = {"zfp": 1.6, "huffman": 2.2, "mgard": 3.5}
 
 def main() -> None:
     data = nyx_like(32)
-    blocks = data.reshape(-1, 64)[:2048]
+    blocks = data.reshape(-1, 64)[:2048].T  # coefficient-major (4^3, N)
 
     for adapter in ("xla", "pallas_interpret"):
         x = jnp.asarray(blocks)

@@ -16,15 +16,18 @@ One chip, in one process:
   huffman     int32 keys of that shape from a skewed 4096-symbol alphabet:
               lossless, bit-exact;
   engine      ``ExecutionEngine.compress_pytree``/``decompress_pytree`` on
-              four 64 MiB ZFP leaves, so the stacked ``shard_map`` bucket
-              runs: streams byte-identical to ``api.compress_leaf``, decodes
-              equal to ``api.decompress_leaf``;
+              four 64 MiB ZFP leaves and four 1 MiB MGARD leaves, so a
+              stacked ``shard_map`` bucket runs for each codec: streams
+              byte-identical to ``api.compress_leaf``, decodes equal to
+              ``api.decompress_leaf``, MGARD within its bound;
   checkpoint  one ``CheckpointManager.save``/``restore`` round trip with a
               32 MiB leaf, which takes the ``chunk_size="auto"`` stream and
               calibrates the cost model on a fresh machine.
 
-``--chips 4`` runs the engine phase over a four-device ``data`` mesh and
-compares it with the one-device serial per-leaf path, nothing else.
+``--chips 4`` runs the engine phase over a four-device ``data`` mesh on the
+four ZFP leaves and compares it with the one-device serial per-leaf path,
+nothing else.  The fan-out is the engine's and the same for every codec's
+bucket; MGARD's stacked bucket is checked on one chip.
 
 Data is generated on the device from ``--seed``.  Every phase prints its
 wall time (compilation included), ratio, max error, the lowering each op
@@ -51,7 +54,8 @@ MGARD_BOUND = 1e-2
 ZFP_RATE = 16
 ALPHABET = 4096
 EDGE = 512            # NYX snapshot edge (SDRBench NYX: 512³ float32)
-LEAF_EDGE = 256       # engine leaves: 256³ float32 = 64 MiB
+LEAF_EDGE = 256       # engine ZFP leaves: 256³ float32 = 64 MiB
+MGARD_LEAF_EDGE = 64   # engine MGARD leaves
 CKPT_SHAPE = (2048, 4096)  # 32 MiB checkpoint leaf
 
 PHASE_OPS = {
@@ -213,7 +217,7 @@ def phase_kernels(devices, kernel: str, n: int) -> None:
         rng.normal(size=(nblk, 64)).astype(np.float32))
     for dims, rate in ((3, ZFP_RATE), (3, 28), (2, 12), (1, 8)):
         bs = 4 ** dims
-        b = blocks.reshape(-1, bs)
+        b = blocks.reshape(-1, bs).T  # coefficient-major (4^d, N)
         both(f"zfp_block_compress[d{dims}r{rate}]", zfp.compress_blocks, b, rate, dims)
         p, e = zfp.compress_blocks(b, rate, dims, adapter="xla")
         both(f"zfp_block_decompress[d{dims}r{rate}]", zfp.decompress_blocks,
@@ -287,13 +291,18 @@ def phase_fields(devices, backend: str, seed: int, edge: int) -> None:
     check(out.dtype == keys.dtype and exact, "huffman round trip is not bit-exact")
 
 
-def _leaf_tree(seed: int, edge: int) -> dict:
+def _leaf_tree(seed: int, edge: int, mgard_edge: int | None) -> dict:
     import numpy as np
 
-    return {f"zfp{i}": np.asarray(nyx_field(seed + 20 + i, edge)) for i in range(4)}
+    tree = {f"zfp{i}": np.asarray(nyx_field(seed + 20 + i, edge)) for i in range(4)}
+    if mgard_edge:
+        tree.update({f"mgard{i}": np.asarray(nyx_field(seed + 24 + i, mgard_edge))
+                     for i in range(4)})
+    return tree
 
 
-def phase_engine(devices, backend: str, seed: int, edge: int) -> None:
+def phase_engine(devices, backend: str, seed: int, edge: int,
+                 mgard_edge: int | None) -> None:
     """Stacked engine buckets vs the serial per-leaf path."""
     import numpy as np
 
@@ -302,9 +311,11 @@ def phase_engine(devices, backend: str, seed: int, edge: int) -> None:
     from repro.launch.mesh import make_data_mesh
 
     kernel = adapters.resolve_backend(backend)
-    tree = _leaf_tree(seed, edge)
+    tree = _leaf_tree(seed, edge, mgard_edge)
 
     def select(key, arr):
+        if key.startswith("mgard"):
+            return "mgard", {"error_bound": MGARD_BOUND, "backend": backend}
         return "zfp", {"rate": ZFP_RATE, "backend": backend}
 
     mesh = make_data_mesh(len(devices))
@@ -319,18 +330,21 @@ def phase_engine(devices, backend: str, seed: int, edge: int) -> None:
         est = eng.stats()
     mesh_ids = tuple(sorted(d.id for d in mesh.devices.flat))
     report("engine", devices, encode_wall_s=t_enc, decode_wall_s=t_dec,
-           leaves=len(tree), leaf_bytes=tree["zfp0"].nbytes,
+           leaves=len(tree), zfp_leaf_bytes=tree["zfp0"].nbytes,
+           mgard_leaf_bytes=tree["mgard0"].nbytes if mgard_edge else 0,
            ratio=stats["ratio"], sharded_leaves=stats["sharded_leaves"],
            sharded_decoded=est["sharded_decoded_leaves"],
            shard_map_calls=est["shard_map_calls"],
            stack_devices=est["stack_devices"], mesh_devices=mesh_ids,
-           lowering=lowerings(PHASE_OPS["zfp"], kernel))
+           lowering=lowerings(PHASE_OPS["zfp"] + (PHASE_OPS["mgard"] if mgard_edge
+                                                  else ()), kernel))
     check(stats["sharded_leaves"] == len(tree), "not every leaf rode a stacked bucket")
     check(est["sharded_decoded_leaves"] == len(tree), "not every leaf decoded stacked")
     check(est["stack_devices"] == mesh_ids,
           f"stacked work on {est['stack_devices']}, mesh is {mesh_ids}")
 
     t0 = time.perf_counter()
+    mgard_err = 0.0
     for key, arr in tree.items():
         method, params = select(key, arr)
         serial = api.compress_leaf(arr, method, **params)
@@ -339,9 +353,14 @@ def phase_engine(devices, backend: str, seed: int, edge: int) -> None:
         ref = api.decompress_leaf(comp[key])
         check(np.array_equal(np.asarray(out[key]), ref),
               f"engine decode of {key} differs from api.decompress_leaf")
+        if method == "mgard":
+            err = float(np.max(np.abs(ref - arr)))
+            bound = MGARD_BOUND * float(np.ptp(arr))
+            check(err <= bound, f"mgard leaf {key}: max error {err} > {bound}")
+            mgard_err = max(mgard_err, err / bound)
     report("engine-vs-serial", devices, wall_s=time.perf_counter() - t0,
            serial_device=devices[0].id, bytes_identical=True,
-           decode_identical=True)
+           decode_identical=True, mgard_max_err_over_bound=mgard_err)
 
 
 def phase_checkpoint(devices, backend: str, seed: int, shape) -> None:
@@ -382,9 +401,11 @@ def phase_checkpoint(devices, backend: str, seed: int, shape) -> None:
 
 
 def run(chips: int, seed: int, *, backend: str = "auto", edge: int = EDGE,
-        leaf_edge: int = LEAF_EDGE, ckpt_shape=CKPT_SHAPE,
-        kernel_n: int = (1 << 22) + 77) -> None:
-    """Every phase for ``chips`` devices (the engine phase only for > 1)."""
+        leaf_edge: int = LEAF_EDGE, mgard_leaf_edge: int = MGARD_LEAF_EDGE,
+        ckpt_shape=CKPT_SHAPE, kernel_n: int = (1 << 22) + 77) -> list:
+    """Every phase for ``chips`` devices (the engine phase only for > 1).
+
+    Returns the devices the phases ran on."""
     import jax
 
     from repro.core import adapters
@@ -393,17 +414,18 @@ def run(chips: int, seed: int, *, backend: str = "auto", edge: int = EDGE,
 
     devices = jax.devices()[:chips]
     if chips > 1:
-        phase_engine(devices, backend, seed, leaf_edge)
-        return
+        phase_engine(devices, backend, seed, leaf_edge, None)
+        return devices
     phases = (
         lambda: phase_kernels(devices, adapters.resolve_backend(backend), kernel_n),
         lambda: phase_fields(devices, backend, seed, edge),
-        lambda: phase_engine(devices, backend, seed, leaf_edge),
+        lambda: phase_engine(devices, backend, seed, leaf_edge, mgard_leaf_edge),
         lambda: phase_checkpoint(devices, backend, seed, ckpt_shape),
     )
     for phase in phases:
         phase()
         GLOBAL_CMM.clear()  # phases share no plan: free their device workspace
+    return devices
 
 
 def main(argv=None) -> int:
@@ -444,16 +466,16 @@ def main(argv=None) -> int:
           f"jax={jax.__version__} compile_cache={cache_dir}", flush=True)
     t0 = time.perf_counter()
     try:
-        run(args.chips, args.seed)
+        used = run(args.chips, args.seed)
     except SmokeFailure as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
         return 1
     print(f"[done] wall_s={time.perf_counter() - t0} "
           f"compile_cache_hits={cache['hits']} misses={cache['misses']}",
           flush=True)
-    d0 = devs[0]
+    d0 = used[0]
     print(json.dumps({"ok": True, "device": {
-        "platform": d0.platform, "kind": d0.device_kind, "count": len(devs),
+        "platform": d0.platform, "kind": d0.device_kind, "count": len(used),
     }}))
     return 0
 

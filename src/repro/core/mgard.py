@@ -114,12 +114,18 @@ def interp_1d(coarse: jax.Array, axis: int) -> jax.Array:
     return jnp.moveaxis(out, 0, axis)
 
 
-# Floating-point contraction.  XLA may fuse ``a*b + c`` into one FMA, and
-# whether it does depends on how a program is fused, which differs between a
-# leaf's own program and the same code vmapped over a stacked bucket.  So the
-# transform below never adds an inexact product: the only multipliers are
-# powers of two (exact), and every inexact scale is a division, which is
-# never contracted.  Stacked and serial programs then round identically.
+# Floating-point contraction.  XLA:CPU fuses ``a*b + c`` into one FMA
+# whenever one fusion holds both, and which ops share a fusion differs
+# between a leaf's own program and the same code vmapped over a stacked
+# bucket.  An optimization barrier does not stop it: XLA:CPU removes
+# barriers before fusion.  So the transform below adds no inexact product
+# it writes itself: its multipliers are powers of two (exact) and its
+# inexact scales are divisions.  XLA still turns a division by a constant
+# into a multiply by the reciprocal (the forward sweep's ``(d*h)/6`` becomes
+# ``d*(h/6)``, fused with the subtraction), so stacked and serial programs
+# round alike only as far as those rewrites match in both; the scan bodies
+# are computations of their own and compile alike.
+# ``tests/test_mgard.py::test_transform_vmapped_matches_serial`` checks it.
 
 
 def mass_mult_1d(x: jax.Array, axis: int, h: float) -> jax.Array:

@@ -298,15 +298,18 @@ def _block_offsets(dims: int):
 
 
 def field_to_blocks(padded: jax.Array) -> jax.Array:
-    """(nb, 4^d) blocks of a block-aligned field, block-major, C order.
+    """(4^d, nb) blocks of a block-aligned field, coefficient-major.
 
-    One strided slice per in-block offset: no intermediate has a 4-wide
-    minor axis, which the TPU's (8, 128) tiling would pad 32× (a 512³
-    field's reshape-transpose blocking needs 16 GiB there).
+    Row ``c`` holds in-block coefficient ``c`` (C order over ``(4,)*d``) of
+    every block, blocks in C order.  This is the ``zfp_block`` kernels'
+    layout: the block batch rides the minor axis.  One strided slice per
+    in-block offset: no intermediate has a 4-wide minor axis, which the
+    TPU's (8, 128) tiling would pad 32× (a 512³ field's reshape-transpose
+    blocking needs 16 GiB there).
     """
     planes = [padded[tuple(slice(o, None, 4) for o in off)]
               for off in _block_offsets(padded.ndim)]
-    return jnp.stack(planes).reshape(len(planes), -1).T
+    return jnp.stack(planes).reshape(len(planes), -1)
 
 
 def blocks_to_field(blocks: jax.Array, padded: tuple[int, ...]) -> jax.Array:
@@ -317,7 +320,7 @@ def blocks_to_field(blocks: jax.Array, padded: tuple[int, ...]) -> jax.Array:
     axis stays a block-count or field axis until the final transpose.
     """
     d = len(padded)
-    a = blocks.T.reshape((4,) * d + tuple(p // 4 for p in padded))
+    a = blocks.reshape((4,) * d + tuple(p // 4 for p in padded))
     for axis in range(d):
         # dims now: (done axes, newest first) + offsets o_axis.. + counts c_axis..
         done = axis
@@ -344,7 +347,7 @@ def compress_jit(
     if adapter is None:
         perm = jnp.asarray(sequency_permutation(dims))
         return _compress_blocks(
-            blocks.reshape((-1,) + block_shape), rate, perm
+            blocks.T.reshape((-1,) + block_shape), rate, perm
         )
     from repro.kernels.zfp_block import ops as zfp_block_ops  # lazy: layer order
 
@@ -361,7 +364,7 @@ def decompress_jit(
         perm = sequency_permutation(dims)
         inv_perm = jnp.asarray(np.argsort(perm).astype(np.int32))
         blocks = _decompress_blocks(payload, emax, rate, inv_perm, block_shape)
-        blocks = blocks.reshape(blocks.shape[0], -1)
+        blocks = blocks.reshape(blocks.shape[0], -1).T
     else:
         from repro.kernels.zfp_block import ops as zfp_block_ops  # lazy
 

@@ -3,11 +3,14 @@
 Fuses per-level bin selection + uniform quantization + zig-zag in one pass
 over the coefficient array.  The coefficients ride a lane-dense
 ``(rows, 128)`` layout; each grid cell stages ``(T, 128)`` of them in VMEM
-and the (tiny) per-level bin table in SMEM.  The bin of each element is a
-compare-and-select over the few levels (the TPU has no vector gather), which
-equals the oracle's table gather for every in-range level id.  The inverse
-kernel fuses the matching dequantize.  This is the masked-dense lowering of
-the paper's Map&Process abstraction (Fig. 3c).
+next to the (tiny) per-level bin table, one level per sublane row broadcast
+across the lanes.  The bin of each element is a compare-and-select over the
+few levels (the TPU has no vector gather), which equals the oracle's table
+gather for every in-range level id.  Every operand is a lane-dense VMEM
+tile, so ``vmap`` (the engine's stacked buckets) batches the kernel with
+legal blocks; an SMEM table would gain a batch row the TPU cannot block.
+The inverse kernel fuses the matching dequantize.  This is the masked-dense
+lowering of the paper's Map&Process abstraction (Fig. 3c).
 """
 
 from __future__ import annotations
@@ -23,28 +26,29 @@ LANES = 128
 DEFAULT_T = 512  # rows of 128 elements per grid cell (256 KiB of f32)
 
 
-def _level_bins(lvl, bins_ref):
-    """Per-element bin by compare-and-select over the level table.
+def _level_bins(lvl, bins_ref, nl: int):
+    """Per-element bin by compare-and-select over the ``nl``-level table.
 
-    Level ids clamp into the table like the oracle's gather does.
+    Row ``l`` of ``bins_ref`` holds level ``l``'s bin in every lane.  Level
+    ids clamp into the table like the oracle's gather does.
     """
-    nl = bins_ref.shape[0]
     lvl = jnp.clip(lvl, 0, nl - 1)
-    b = jnp.full(lvl.shape, bins_ref[0], jnp.float32)
+    b = jnp.broadcast_to(bins_ref[0:1, :], lvl.shape)
     for level in range(1, nl):
-        b = jnp.where(lvl == level, bins_ref[level], b)
+        b = jnp.where(lvl == level, bins_ref[level:level + 1, :], b)
     return b
 
 
-def _quant_kernel(x_ref, lvl_ref, bins_ref, q_ref):
-    q = jnp.round(x_ref[...] / _level_bins(lvl_ref[...], bins_ref)).astype(jnp.int32)
+def _quant_kernel(x_ref, lvl_ref, bins_ref, q_ref, *, nl):
+    q = jnp.round(x_ref[...] / _level_bins(lvl_ref[...], bins_ref, nl))
+    q = q.astype(jnp.int32)
     q_ref[...] = (q << 1) ^ (q >> 31)  # zig-zag (bit pattern of the uint32 key)
 
 
-def _dequant_kernel(u_ref, lvl_ref, bins_ref, x_ref):
+def _dequant_kernel(u_ref, lvl_ref, bins_ref, x_ref, *, nl):
     u = u_ref[...]  # zig-zagged keys, carried as int32 bit patterns
     q = jax.lax.shift_right_logical(u, 1) ^ -(u & 1)
-    x_ref[...] = q.astype(jnp.float32) * _level_bins(lvl_ref[...], bins_ref)
+    x_ref[...] = q.astype(jnp.float32) * _level_bins(lvl_ref[...], bins_ref, nl)
 
 
 def _lane_dense(n: int, t: int) -> tuple[int, int]:
@@ -60,16 +64,21 @@ def _map_call(kernel, a, levels, bins, out_dtype, t, interpret):
     pad = rows * LANES - n
     a = jnp.pad(a, (0, pad)).reshape(rows, LANES)
     levels = jnp.pad(levels, (0, pad)).reshape(rows, LANES)
+    nl = bins.shape[0]
+    table_rows = -(-nl // 8) * 8
+    table = jnp.broadcast_to(bins.astype(jnp.float32)[:, None], (nl, LANES))
+    table = jnp.pad(table, ((0, table_rows - nl), (0, 0)))
     block = pl.BlockSpec((tile, LANES), lambda i: (i, 0))
     out = pl.pallas_call(
-        kernel,
+        functools.partial(kernel, nl=nl),
         grid=(rows // tile,),
-        in_specs=[block, block, pl.BlockSpec(memory_space=pltpu.SMEM)],
+        in_specs=[block, block,
+                  pl.BlockSpec((table_rows, LANES), lambda i: (0, 0))],
         out_specs=block,
         out_shape=jax.ShapeDtypeStruct((rows, LANES), out_dtype),
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(a, levels, bins.astype(jnp.float32))
+    )(a, levels, table)
     return out.reshape(-1)[:n]
 
 

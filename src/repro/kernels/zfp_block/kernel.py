@@ -2,8 +2,8 @@
 
 One grid cell processes ``TR × 128`` 4^d blocks staged in VMEM (the paper's
 block→SM mapping becomes block-batch→grid-cell).  The blocks ride the
-lanes: the wrapper lays the batch out coefficient-major as
-``(4^d, blocks/128, 128)``, so every block coefficient is one full
+lanes: blocks arrive and leave coefficient-major, ``(4^d, blocks)``, and
+the wrapper only pads and reshapes them to ``(4^d, blocks/128, 128)``, so every block coefficient is one full
 ``(TR, 128)`` vector and all five stages (exponent align → lift →
 negabinary → permute → bitplane pack) are elementwise vector ops fused in
 VMEM — the multi-stage GEM execution of Table I/II.  The lifting partners
@@ -120,20 +120,20 @@ def _params():
 
 @functools.partial(jax.jit, static_argnames=("rate", "dims", "tr", "interpret"))
 def compress_blocks(
-    blocks: jax.Array,  # (N, 4^dims) float32
+    blocks: jax.Array,  # (4^dims, N) float32, coefficient-major
     rate: int,
     dims: int,
     *,
     interpret: bool,
     tr: int = DEFAULT_TR,
 ) -> tuple[jax.Array, jax.Array]:
-    n, bs = blocks.shape
+    bs, n = blocks.shape
     if bs != 4 ** dims:
         raise ValueError(f"blocks of {bs} values are not 4^{dims}")
     wpb = core_zfp.words_per_block(bs, rate)
     tr, rows = _lanes_tiles(n, tr)
-    x = jnp.pad(blocks.astype(jnp.float32), ((0, rows * LANES - n), (0, 0)))
-    x = x.T.reshape(bs, rows, LANES)
+    x = jnp.pad(blocks.astype(jnp.float32), ((0, 0), (0, rows * LANES - n)))
+    x = x.reshape(bs, rows, LANES)
     payload, emax = pl.pallas_call(
         functools.partial(_compress_kernel, rate=rate, dims=dims),
         grid=(rows // tr,),
@@ -162,7 +162,7 @@ def decompress_blocks(
     *,
     interpret: bool,
     tr: int = DEFAULT_TR,
-) -> jax.Array:
+) -> jax.Array:  # (4^dims, N) float32, coefficient-major
     n, wpb = payload.shape
     bs = 4 ** dims
     tr, rows = _lanes_tiles(n, tr)
@@ -182,4 +182,4 @@ def decompress_blocks(
         compiler_params=_params(),
         interpret=interpret,
     )(p, e)
-    return out.reshape(bs, rows * LANES).T[:n]
+    return out.reshape(bs, rows * LANES)[:, :n]

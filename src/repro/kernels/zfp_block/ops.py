@@ -40,10 +40,12 @@ def _decompress_interp(payload, emax, rate, dims):
 
 
 def compress_blocks(blocks: jax.Array, rate: int, dims: int, adapter: str | None = None):
+    """(4^dims, N) coefficient-major blocks → ((N, wpb) uint32, (N,) int32)."""
     return adapters.dispatch("zfp_block_compress", adapter)(blocks, rate, dims)
 
 
 def decompress_blocks(
     payload: jax.Array, emax: jax.Array, rate: int, dims: int, adapter: str | None = None
 ):
+    """((N, wpb) uint32, (N,) int32) → (4^dims, N) coefficient-major blocks."""
     return adapters.dispatch("zfp_block_decompress", adapter)(payload, emax, rate, dims)

@@ -45,7 +45,7 @@ def test_huffman_int_roundtrip(rng):
 def test_cross_adapter_bitstream_portability(rng):
     """Compress with the Pallas kernel, decompress with the XLA oracle (and
     vice versa) — the paper's cross-architecture data portability claim."""
-    blocks = rng.normal(size=(64, 64)).astype(np.float32)
+    blocks = rng.normal(size=(64, 64)).astype(np.float32).T  # (4^3, N)
     for enc_a, dec_a in [("pallas_interpret", "xla"), ("xla", "pallas_interpret")]:
         p, e = zfp_ops.compress_blocks(jnp.asarray(blocks), 16, 3, adapter=enc_a)
         out = np.asarray(zfp_ops.decompress_blocks(p, e, 16, 3, adapter=dec_a))

@@ -109,7 +109,7 @@ def test_kernel_ops_adapter_parity(rng):
     from repro.kernels.zfp_block import ops as zfp_ops
 
     a, b = "xla", "pallas_interpret"
-    blocks = rng.normal(size=(40, 64)).astype(np.float32)
+    blocks = rng.normal(size=(40, 64)).astype(np.float32).T  # (4^3, N)
     for enc, dec in ((a, b), (b, a)):
         p, e = zfp_ops.compress_blocks(jnp.asarray(blocks), 12, 3, adapter=enc)
         p2, e2 = zfp_ops.compress_blocks(jnp.asarray(blocks), 12, 3, adapter=dec)

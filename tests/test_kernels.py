@@ -21,8 +21,9 @@ def test_zfp_block_kernel(dims, rate, rng):
     blocks = (rng.normal(size=(130, bs)) * 10.0 ** rng.integers(-3, 4, (130, 1))).astype(
         np.float32
     )
-    p_k, e_k = zfp_ops.compress_blocks(jnp.asarray(blocks), rate, dims, adapter="pallas_interpret")
-    p_r, e_r = zfp_ops.compress_blocks(jnp.asarray(blocks), rate, dims, adapter="xla")
+    blocks = jnp.asarray(blocks.T)  # coefficient-major (4^d, N)
+    p_k, e_k = zfp_ops.compress_blocks(blocks, rate, dims, adapter="pallas_interpret")
+    p_r, e_r = zfp_ops.compress_blocks(blocks, rate, dims, adapter="xla")
     np.testing.assert_array_equal(np.asarray(p_k), np.asarray(p_r))
     np.testing.assert_array_equal(np.asarray(e_k), np.asarray(e_r))
     out_k = zfp_ops.decompress_blocks(p_k, e_k, rate, dims, adapter="pallas_interpret")

@@ -19,6 +19,26 @@ def test_decompose_recompose_lossless(shape, rng):
     assert np.abs(r - u).max() < 5e-6
 
 
+@pytest.mark.parametrize(
+    "shape", [(65,), (17, 17), (33, 17), (9, 9, 9), (16, 16, 16), (20, 30, 40)]
+)
+def test_transform_vmapped_matches_serial(shape, rng):
+    """A stacked bucket vmaps the transform; it must round like one leaf's
+    own program (README invariant 1), whatever XLA fuses differently."""
+    import jax
+
+    u = jnp.asarray(rng.normal(size=(3,) + shape).astype(np.float32) * 10)
+    dec = lambda x: mgard.decompose(x, shape)  # noqa: E731
+    rec = lambda c: mgard.recompose(c, shape)  # noqa: E731
+    c_serial = jnp.stack([jax.jit(dec)(x) for x in u])
+    np.testing.assert_array_equal(np.asarray(jax.jit(jax.vmap(dec))(u)),
+                                  np.asarray(c_serial))
+    c = c_serial + jnp.asarray(rng.normal(size=c_serial.shape).astype(np.float32))
+    r_serial = jnp.stack([jax.jit(rec)(x) for x in c])
+    np.testing.assert_array_equal(np.asarray(jax.jit(jax.vmap(rec))(c)),
+                                  np.asarray(r_serial))
+
+
 def test_error_bound_smooth():
     f = smooth_field_3d(48)
     vr = float(f.max() - f.min())

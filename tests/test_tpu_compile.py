@@ -2,7 +2,9 @@
 
 Every Pallas kernel the ``pallas`` adapter dispatches on the main path is
 compiled at the width of a NYX 512³ field (513³ after MGARD's dyadic
-padding), and so are MGARD's decompose and recompose.  The TPU compiler
+padding), and so are MGARD's decompose and recompose.  Each kernel is
+compiled a second time under ``vmap`` over four 64 MiB leaves, as the
+engine's stacked buckets run it.  The TPU compiler
 refuses here what the chip would refuse: unaligned blocks, unsupported
 gathers or reductions, more VMEM/SMEM than a kernel may use, a program
 larger than the chip's memory.  Nothing runs, so this says nothing about
@@ -26,9 +28,10 @@ from repro.kernels.zfp_block import kernel as zfp_k
 
 NYX = (512, 512, 512)
 PADDED = 513 ** 3           # MGARD's dyadic padding of a 512³ field
+LEAF = 257 ** 3             # a padded 256³ (64 MiB) engine leaf
+STACK = 4                   # leaves in a stacked engine bucket
 LEVELS = 10                 # per-level bins of a 513³ grid: L + 1 with L = 9
 CHUNK = 4096                # huffman.DEFAULT_CHUNK
-ZFP_BLOCKS = 512 ** 3 // 64
 HBM_BYTES = 16e9            # one v5e chip
 
 
@@ -60,9 +63,10 @@ def no_persistent_cache():
     cc.reset_cache()
 
 
-def _kernel_cases():
-    """name → (fn, [(shape, dtype), ...]); interpret=False is the chip path."""
-    n = PADDED
+def _kernel_cases(n: int = PADDED):
+    """name → (fn, [(shape, dtype), ...]) for ``n`` elements; interpret=False
+    is the chip path."""
+    zfp_blocks = -(-n // 64)
     return {
         "quantize_map": (
             lambda x, l, b: quant_k.quantize(x, l, b, interpret=False),
@@ -98,11 +102,11 @@ def _kernel_cases():
         ),
         "zfp_block_compress": (
             lambda b: zfp_k.compress_blocks(b, 16, 3, interpret=False),
-            [((ZFP_BLOCKS, 64), jnp.float32)],
+            [((64, zfp_blocks), jnp.float32)],
         ),
         "zfp_block_decompress": (
             lambda p, e: zfp_k.decompress_blocks(p, e, 16, 3, interpret=False),
-            [((ZFP_BLOCKS, 32), jnp.uint32), ((ZFP_BLOCKS,), jnp.int32)],
+            [((zfp_blocks, 32), jnp.uint32), ((zfp_blocks,), jnp.int32)],
         ),
     }
 
@@ -123,6 +127,16 @@ def test_kernel_compiles_for_v5e(name, one_chip):
     fn, args = _kernel_cases()[name]
     compiled = _compile(fn, args, one_chip)
     assert "tpu_custom_call" in compiled.as_text()  # the Pallas kernel is there
+    assert _fits(compiled)
+
+
+@pytest.mark.parametrize("name", sorted(_kernel_cases()))
+def test_kernel_vmapped_compiles_for_v5e(name, one_chip):
+    """The engine vmaps each kernel over a stacked bucket of leaves."""
+    fn, args = _kernel_cases(LEAF)[name]
+    compiled = _compile(jax.vmap(fn), [((STACK,) + s, d) for s, d in args],
+                        one_chip)
+    assert "tpu_custom_call" in compiled.as_text()
     assert _fits(compiled)
 
 
