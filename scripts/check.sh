@@ -9,10 +9,6 @@
 #   scripts/check.sh bench          benchmark smoke mode: fig16 engine
 #                                   throughput on a 1×CPU mesh
 #                                   -> BENCH_engine.json
-#   scripts/check.sh bench stages   per-stage pipeline timings (encode AND
-#                                   decode) + host<->device transfer bytes
-#                                   per codec (smoke-sized)
-#                                   -> BENCH_stages.json
 #   scripts/check.sh bench pipeline chunk-pipeline overlap: pipelined vs
 #                                   serial wall clock, per-lane timings,
 #                                   bit-identity check
@@ -64,12 +60,6 @@ if [[ "${1:-}" == "fast" ]]; then
 fi
 if [[ "${1:-}" == "bench" ]]; then
   shift
-  if [[ "${1:-}" == "stages" ]]; then
-    shift
-    PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} \
-      python -m benchmarks.stage_breakdown --smoke --out BENCH_stages.json "$@"
-    exit 0
-  fi
   if [[ "${1:-}" == "pipeline" ]]; then
     shift
     PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} \

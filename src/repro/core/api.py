@@ -66,6 +66,7 @@ from .codecs.base import Codec, ReductionPlan, ReductionSpec  # noqa: F401
 from .container import Compressed, ContainerError, _jsonable  # noqa: F401
 from .context import GLOBAL_CMM, ReductionContext
 from .stages.base import CallEnv, Stage, StageGraph, TransferStats  # noqa: F401
+from ..runtime.spans import root, span
 
 METHODS = ("mgard", "mgard-progressive", "zfp", "huffman", "huffman-bytes")
 
@@ -96,7 +97,8 @@ def make_spec(data: Any, method: str, **params: Any) -> ReductionSpec:
 
 
 def _build_context(key, codec: Codec, spec: ReductionSpec) -> ReductionContext:
-    plan = codec.plan(spec)
+    with span("hpdr.plan.build", method=spec.method):
+        plan = codec.plan(spec)
     # Mirror the plan's persistent buffers into the context so CMM byte
     # accounting (ContextCache.nbytes/stats) sees them.
     return ReductionContext(key=key, plan=plan, buffers=plan.workspace)
@@ -113,28 +115,14 @@ def get_plan(spec: ReductionSpec) -> ReductionPlan:
     return ctx.plan
 
 
+def _raw_bytes(spec: ReductionSpec) -> int:
+    return math.prod(spec.shape) * jnp.dtype(spec.dtype).itemsize
+
+
 def encode(spec: ReductionSpec, data: jax.Array | np.ndarray) -> Compressed:
     """Compress ``data`` according to ``spec`` (plan reused via the CMM)."""
-    return get_codec(spec.method).encode(get_plan(spec), data)
-
-
-def encode_profiled(
-    spec: ReductionSpec, data: jax.Array | np.ndarray
-) -> tuple[Compressed, dict[str, float], "TransferStats"]:
-    """Encode with per-stage observability (the ``bench stages`` hook).
-
-    Returns ``(container, stage_seconds, transfers)``: wall time per
-    pipeline stage (device segments blocked on for honest timings) and the
-    run's host↔device transfer bytes — the quantities
-    ``scripts/check.sh bench stages`` tracks against the paper's
-    2.3%-transfer claim.
-    """
-    codec = get_codec(spec.method)
-    plan = get_plan(spec)
-    env = CallEnv(plan)
-    profile: dict[str, float] = {}
-    c = codec.encode(plan, data, env=env, profile=profile)
-    return c, profile, env.transfers
+    with root("hpdr.compress", method=spec.method, raw_bytes=_raw_bytes(spec)):
+        return get_codec(spec.method).encode(get_plan(spec), data)
 
 
 def decode(c: Compressed, backend: str | None = None) -> jax.Array:
@@ -150,29 +138,8 @@ def decode(c: Compressed, backend: str | None = None) -> jax.Array:
     spec = codec.decode_spec(c)
     if backend is not None:
         spec = dataclasses.replace(spec, backend=adapters.resolve_backend(backend))
-    return codec.decode(get_plan(spec), c)
-
-
-def decode_profiled(
-    c: Compressed, backend: str | None = None
-) -> tuple[jax.Array, dict[str, float], "TransferStats"]:
-    """Decode with per-stage observability (the ``bench stages`` decode hook).
-
-    Returns ``(array, stage_seconds, transfers)``: wall time per inverse
-    pipeline step (host prepares + the fused inverse segments, blocked on
-    for honest timings) and the run's transfer bytes — on the pipeline
-    path H2D is exactly the compressed sections plus the metadata-scale
-    decode operands, never a raw-array-sized staging transfer.
-    """
-    codec = get_codec(c.method)
-    spec = codec.decode_spec(c)
-    if backend is not None:
-        spec = dataclasses.replace(spec, backend=adapters.resolve_backend(backend))
-    plan = get_plan(spec)
-    env = CallEnv(plan)
-    profile: dict[str, float] = {}
-    out = codec.decode(plan, c, env=env, profile=profile)
-    return out, profile, env.transfers
+    with root("hpdr.decompress", method=c.method, raw_bytes=_raw_bytes(spec)):
+        return codec.decode(get_plan(spec), c)
 
 
 # ---------------------------------------------------------------------------

@@ -179,7 +179,6 @@ class Codec:
         data: Any,
         *,
         env: Any = None,
-        profile: dict | None = None,
         workspace: dict | None = None,
     ) -> tuple[dict, Any]:
         """Phase 1 of a two-phase encode: run the forward pipeline only.
@@ -196,8 +195,7 @@ class Codec:
                 "encode() or implement build_stages()"
             )
         return plan.pipeline.run(
-            self.encode_input(plan, data), env=env, profile=profile,
-            workspace=workspace,
+            self.encode_input(plan, data), env=env, workspace=workspace
         )
 
     def encode_finish(self, plan: ReductionPlan, state: dict, env: Any) -> Compressed:
@@ -212,16 +210,15 @@ class Codec:
         data: jax.Array,
         *,
         env: Any = None,
-        profile: dict | None = None,
     ) -> Compressed:
         """Default encode: run the compiled stage pipeline, then serialise.
 
-        ``env``/``profile`` are the observability hooks ``api.encode_profiled``
-        threads through (per-stage wall timings, host↔device transfer bytes).
-        Exactly :meth:`encode_begin` followed by :meth:`encode_finish`, so
-        the pipelined two-phase path is bit-identical by construction.
+        ``env`` is the caller's :class:`~repro.core.stages.base.CallEnv`,
+        whose ``transfers`` count the call's host↔device bytes.  Exactly
+        :meth:`encode_begin` followed by :meth:`encode_finish`, so the
+        pipelined two-phase path is bit-identical by construction.
         """
-        state, env = self.encode_begin(plan, data, env=env, profile=profile)
+        state, env = self.encode_begin(plan, data, env=env)
         return self.encode_finish(plan, state, env)
 
     def decode(
@@ -230,7 +227,6 @@ class Codec:
         c: Compressed,
         *,
         env: Any = None,
-        profile: dict | None = None,
     ) -> jax.Array:
         raise NotImplementedError
 
@@ -277,7 +273,6 @@ class Codec:
         plan: ReductionPlan,
         c: Compressed,
         env: Any = None,
-        profile: dict | None = None,
     ) -> jax.Array | None:
         """Run the compiled inverse pipeline; None → caller's host fallback."""
         if plan.pipeline is None or not plan.pipeline.invertible:
@@ -290,7 +285,7 @@ class Codec:
 
         env = env if env is not None else CallEnv(plan)
         env.meta.update(meta)
-        state, env = plan.pipeline.invert(state0, env=env, profile=profile)
+        state, env = plan.pipeline.invert(state0, env=env)
         return self.finish_decode(plan, env, state, c)
 
     @property

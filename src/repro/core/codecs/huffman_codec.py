@@ -236,8 +236,8 @@ class HuffmanCodec(Codec):
         return self._attach_pipeline(plan)
 
     def encode_input(self, plan: ReductionPlan, data: Any) -> dict:
-        data = jnp.asarray(data)
-        if not jnp.issubdtype(data.dtype, jnp.integer):
+        # the pipeline uploads (and counts) host input itself
+        if not jnp.issubdtype(jnp.result_type(data), jnp.integer):
             raise ValueError("huffman method expects integer keys; use huffman-bytes")
         return {"data": data}
 
@@ -256,9 +256,9 @@ class HuffmanCodec(Codec):
 
     def decode(
         self, plan: ReductionPlan, c: Compressed, *,
-        env=None, profile: dict | None = None,
+        env=None,
     ) -> jax.Array:
-        out = self._pipeline_decode(plan, c, env=env, profile=profile)
+        out = self._pipeline_decode(plan, c, env=env)
         if out is not None:
             return out
         # host fallback: streams without a decode chunk index
@@ -328,9 +328,9 @@ class HuffmanBytesCodec(Codec):
 
     def decode(
         self, plan: ReductionPlan, c: Compressed, *,
-        env=None, profile: dict | None = None,
+        env=None,
     ) -> jax.Array:
-        out = self._pipeline_decode(plan, c, env=env, profile=profile)
+        out = self._pipeline_decode(plan, c, env=env)
         if out is not None:
             return out
         enc = sections_to_encoded(c)

@@ -172,9 +172,9 @@ class MGARDCodec(Codec):
 
     def decode(
         self, plan: ReductionPlan, c: Compressed, *,
-        env=None, profile: dict | None = None,
+        env=None,
     ) -> jax.Array:
-        out = self._pipeline_decode(plan, c, env=env, profile=profile)
+        out = self._pipeline_decode(plan, c, env=env)
         if out is not None:
             return out
         # host fallback: streams without a decode chunk index

@@ -53,7 +53,7 @@ class ProgressiveMGARDCodec(Codec):
 
     def encode(
         self, plan: ReductionPlan, data: jax.Array, *,
-        env=None, profile: dict | None = None,
+        env=None,
     ) -> Compressed:
         from .. import progressive  # lazy: codecs package loads before it
 
@@ -80,7 +80,7 @@ class ProgressiveMGARDCodec(Codec):
 
     def decode(
         self, plan: ReductionPlan, c: Compressed, *,
-        env=None, profile: dict | None = None,
+        env=None,
     ) -> jax.Array:
         from .. import progressive  # lazy
 

@@ -84,9 +84,9 @@ class ZFPCodec(Codec):
 
     def decode(
         self, plan: ReductionPlan, c: Compressed, *,
-        env=None, profile: dict | None = None,
+        env=None,
     ) -> jax.Array:
-        out = self._pipeline_decode(plan, c, env=env, profile=profile)
+        out = self._pipeline_decode(plan, c, env=env)
         if out is not None:
             return out
         out = plan.executables["decode"](

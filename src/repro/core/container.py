@@ -39,6 +39,8 @@ from typing import Any
 
 import numpy as np
 
+from ..runtime.spans import span
+
 MAGIC = b"HPDR"
 CONTAINER_VERSION = 2
 _HEADER_FIXED = 16  # magic + version + header-length words
@@ -120,37 +122,41 @@ class Compressed:
         if version != 2:
             raise ValueError(f"cannot write container version {version}")
         names = sorted(self.arrays)
-        sections: dict[str, dict] = {}
-        payload = io.BytesIO()
-        for n in names:
-            raw = np.ascontiguousarray(self.arrays[n]).tobytes()
-            sections[n] = {
-                "dtype": str(self.arrays[n].dtype),
-                "shape": list(self.arrays[n].shape),
-                "offset": payload.tell(),
-                "nbytes": len(raw),
-                # per-section checksum (additive): lets a reader verify and
-                # decode one section — e.g. a progressive component prefix —
-                # without touching the rest of the payload
-                "crc32": zlib.crc32(raw) & 0xFFFFFFFF,
+        size = self.nbytes()
+        with span("hpdr.to_bytes", bytes=size):
+            with span("hpdr.to_bytes.copy", bytes=size):
+                raws = [np.ascontiguousarray(self.arrays[n]).tobytes() for n in names]
+            # per-section checksums (additive): let a reader verify and decode
+            # one section — e.g. a progressive component prefix — without
+            # touching the rest of the payload
+            with span("hpdr.to_bytes.crc32", bytes=size):
+                crcs = [crc32_of(raw) for raw in raws]
+            sections: dict[str, dict] = {}
+            offset = 0
+            for n, raw, crc in zip(names, raws, crcs):
+                sections[n] = {
+                    "dtype": str(self.arrays[n].dtype),
+                    "shape": list(self.arrays[n].shape),
+                    "offset": offset,
+                    "nbytes": len(raw),
+                    "crc32": crc,
+                }
+                offset += len(raw)
+            with span("hpdr.to_bytes.copy", bytes=offset):
+                pbytes = b"".join(raws)
+            with span("hpdr.to_bytes.crc32", bytes=offset):
+                crc = crc32_of(pbytes)
+            header = {
+                "method": self.method,
+                "meta": _jsonable(self.meta),
+                "sections": sections,
+                "payload_bytes": offset,
+                "crc32": crc,
             }
-            payload.write(raw)
-        pbytes = payload.getvalue()
-        header = {
-            "method": self.method,
-            "meta": _jsonable(self.meta),
-            "sections": sections,
-            "payload_bytes": len(pbytes),
-            "crc32": zlib.crc32(pbytes) & 0xFFFFFFFF,
-        }
-        hbytes = json.dumps(header).encode()
-        buf = io.BytesIO()
-        buf.write(MAGIC)
-        buf.write(np.uint32(2).tobytes())
-        buf.write(np.uint64(len(hbytes)).tobytes())
-        buf.write(hbytes)
-        buf.write(pbytes)
-        return buf.getvalue()
+            hbytes = json.dumps(header).encode()
+            fixed = MAGIC + np.uint32(2).tobytes() + np.uint64(len(hbytes)).tobytes()
+            with span("hpdr.to_bytes.copy", bytes=len(fixed) + len(hbytes) + offset):
+                return b"".join((fixed, hbytes, pbytes))
 
     def _to_bytes_v1(self) -> bytes:
         buf = io.BytesIO()
@@ -174,28 +180,15 @@ class Compressed:
 
     @classmethod
     def from_bytes(cls, raw: bytes) -> "Compressed":
-        raw = bytes(raw)
-        if len(raw) < _HEADER_FIXED:
-            raise ContainerError(
-                f"truncated HPDR stream: {len(raw)} bytes < {_HEADER_FIXED}-byte header"
-            )
-        if raw[:4] != MAGIC:
-            raise ContainerError("not an HPDR stream")
-        version = int(np.frombuffer(raw[4:8], np.uint32)[0])
-        if version not in (1, 2):
-            raise ContainerError(
-                f"unsupported HPDR container version {version} (supported: 1, 2)"
-            )
-        hlen = int(np.frombuffer(raw[8:16], np.uint64)[0])
-        if len(raw) < _HEADER_FIXED + hlen:
-            raise ContainerError("truncated HPDR stream: incomplete header")
-        try:
-            header = json.loads(raw[_HEADER_FIXED : _HEADER_FIXED + hlen].decode())
-        except (UnicodeDecodeError, json.JSONDecodeError) as e:
-            raise ContainerError(f"corrupt HPDR header: {e}") from e
-        if version == 1:
-            return cls._from_bytes_v1(raw, header, _HEADER_FIXED + hlen)
-        return cls._from_bytes_v2(raw, header, _HEADER_FIXED + hlen)
+        with span("hpdr.from_bytes", bytes=len(raw)):
+            if not isinstance(raw, bytes):  # bytes(raw) of bytes copies nothing
+                with span("hpdr.from_bytes.copy", bytes=len(raw)):
+                    raw = bytes(raw)
+            with span("hpdr.from_bytes.parse"):
+                header, version, base = _parse_header(raw)
+            if version == 1:
+                return cls._from_bytes_v1(raw, header, base)
+            return cls._from_bytes_v2(raw, header, base)
 
     @classmethod
     def _from_bytes_v1(cls, raw: bytes, header: dict, off: int) -> "Compressed":
@@ -210,7 +203,8 @@ class Compressed:
                     f"truncated HPDR stream: section {n!r} needs {nb} bytes "
                     f"at offset {off}, stream has {len(raw)}"
                 )
-            arrays[n] = np.frombuffer(raw[off : off + nb], dt).reshape(spec["shape"])
+            with span("hpdr.from_bytes.copy", bytes=nb):
+                arrays[n] = np.frombuffer(raw[off : off + nb], dt).reshape(spec["shape"])
             off += nb
         return cls(method=header["method"], meta=header["meta"], arrays=arrays)
 
@@ -222,16 +216,42 @@ class Compressed:
                 f"truncated HPDR stream: payload needs {pbytes} bytes, "
                 f"stream has {len(raw) - base} after header"
             )
-        payload = raw[base : base + pbytes]
-        check_crc32(payload, header["crc32"], "HPDR payload")
+        with span("hpdr.from_bytes.copy", bytes=pbytes):
+            payload = raw[base : base + pbytes]
+        with span("hpdr.from_bytes.crc32", bytes=pbytes):
+            check_crc32(payload, header["crc32"], "HPDR payload")
         arrays = {}
         for n, spec in header["sections"].items():
             dt = np.dtype(spec["dtype"])
             lo, hi = spec["offset"], spec["offset"] + spec["nbytes"]
             if hi > pbytes:
                 raise ContainerError(f"corrupt HPDR stream: section {n!r} out of bounds")
-            arrays[n] = np.frombuffer(payload[lo:hi], dt).reshape(spec["shape"])
+            with span("hpdr.from_bytes.copy", bytes=hi - lo):
+                arrays[n] = np.frombuffer(payload[lo:hi], dt).reshape(spec["shape"])
         return cls(method=header["method"], meta=header["meta"], arrays=arrays)
+
+
+def _parse_header(raw: bytes) -> tuple[dict, int, int]:
+    """``(header, version, payload_base)`` of a v1 or v2 stream."""
+    if len(raw) < _HEADER_FIXED:
+        raise ContainerError(
+            f"truncated HPDR stream: {len(raw)} bytes < {_HEADER_FIXED}-byte header"
+        )
+    if raw[:4] != MAGIC:
+        raise ContainerError("not an HPDR stream")
+    version = int(np.frombuffer(raw[4:8], np.uint32)[0])
+    if version not in (1, 2):
+        raise ContainerError(
+            f"unsupported HPDR container version {version} (supported: 1, 2)"
+        )
+    hlen = int(np.frombuffer(raw[8:16], np.uint64)[0])
+    if len(raw) < _HEADER_FIXED + hlen:
+        raise ContainerError("truncated HPDR stream: incomplete header")
+    try:
+        header = json.loads(raw[_HEADER_FIXED : _HEADER_FIXED + hlen].decode())
+    except (UnicodeDecodeError, json.JSONDecodeError) as e:
+        raise ContainerError(f"corrupt HPDR header: {e}") from e
+    return header, version, _HEADER_FIXED + hlen
 
 
 # ---------------------------------------------------------------------------
@@ -246,27 +266,13 @@ def peek_header(raw: bytes) -> tuple[dict, int]:
     directory with offsets; v1 streams raise — callers wanting v1 compat go
     through :meth:`Compressed.from_bytes`.
     """
-    raw = bytes(raw)
-    if len(raw) < _HEADER_FIXED:
-        raise ContainerError(
-            f"truncated HPDR stream: {len(raw)} bytes < {_HEADER_FIXED}-byte header"
-        )
-    if raw[:4] != MAGIC:
-        raise ContainerError("not an HPDR stream")
-    version = int(np.frombuffer(raw[4:8], np.uint32)[0])
+    header, version, base = _parse_header(bytes(raw))
     if version != 2:
         raise ContainerError(
             f"HPDR container version {version} has no section directory "
             "(partial reads need v2)"
         )
-    hlen = int(np.frombuffer(raw[8:16], np.uint64)[0])
-    if len(raw) < _HEADER_FIXED + hlen:
-        raise ContainerError("truncated HPDR stream: incomplete header")
-    try:
-        header = json.loads(raw[_HEADER_FIXED : _HEADER_FIXED + hlen].decode())
-    except (UnicodeDecodeError, json.JSONDecodeError) as e:
-        raise ContainerError(f"corrupt HPDR header: {e}") from e
-    return header, _HEADER_FIXED + hlen
+    return header, base
 
 
 def read_section_bytes(raw: bytes, name: str) -> bytes:

@@ -54,6 +54,7 @@ from .codecs.base import ReductionSpec
 from .container import Compressed
 from .stages.base import CallEnv, LeafView, TransferStats
 from ..runtime.executor import COMPUTE, MESH, DeviceExecutor, Submission
+from ..runtime.spans import root, span
 
 
 def data_devices(mesh: Mesh | None) -> list:
@@ -131,6 +132,12 @@ class ExecutionEngine:
         self.sharded_decoded_leaves = 0
         self.transfer_h2d = 0
         self.transfer_d2h = 0
+        #: raw-array copies at the pytree surface, beside the pipeline's own
+        #: ``transfer_*``: device leaves fetched to the host for the leaf
+        #: policy, decoded leaves fetched for restore, restored leaves
+        #: uploaded again
+        self.surface_h2d = 0
+        self.surface_d2h = 0
         self.ws_stack_builds = 0
         self.ws_donated_calls = 0
 
@@ -150,7 +157,7 @@ class ExecutionEngine:
         from . import api
 
         return self.executor.submit(
-            lambda: api.encode(spec, jnp.asarray(data)), device=device
+            lambda: api.encode(spec, data), device=device
         )
 
     def submit_decode(self, c: Compressed, device: Any = None) -> Submission:
@@ -231,27 +238,32 @@ class ExecutionEngine:
         order: list[str] = []
         raw_leaves: dict[str, np.ndarray] = {}
         jobs: list[tuple[str, np.ndarray, np.ndarray, ReductionSpec]] = []
-        for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
-            key = api._path_key(path, sep)
-            if owned_only and not self.topology.owns(key):
-                stats["remote_leaves"] += 1
-                continue
-            arr = np.asarray(leaf)
-            order.append(key)
-            stats["raw"] += arr.nbytes
-            stats["leaves"] += 1
-            choice = select(key, arr)
-            if choice is None:
-                raw_leaves[key] = arr
-                stats["compressed"] += arr.nbytes
-                continue
-            method, params = choice
-            x, pol_method, pol_params = api.leaf_policy(arr, method, params)
-            # a per-leaf backend in the policy overrides the engine default
-            backend = pol_params.pop("backend", None) or self.backend
-            spec = api.make_spec(x, pol_method, backend=backend, **pol_params)
-            api.get_plan(spec)
-            jobs.append((key, arr, x, spec))
+        leaves = jax.tree_util.tree_flatten_with_path(tree)[0]
+        surface = TransferStats()
+        with span("hpdr.engine.leaf_jobs", leaves=len(leaves),
+                  bytes=sum(int(getattr(v, "nbytes", 0)) for _p, v in leaves)):
+            for path, leaf in leaves:
+                key = api._path_key(path, sep)
+                if owned_only and not self.topology.owns(key):
+                    stats["remote_leaves"] += 1
+                    continue
+                arr = surface.fetch(leaf)
+                order.append(key)
+                stats["raw"] += arr.nbytes
+                stats["leaves"] += 1
+                choice = select(key, arr)
+                if choice is None:
+                    raw_leaves[key] = arr
+                    stats["compressed"] += arr.nbytes
+                    continue
+                method, params = choice
+                x, pol_method, pol_params = api.leaf_policy(arr, method, params)
+                # a per-leaf backend in the policy overrides the engine default
+                backend = pol_params.pop("backend", None) or self.backend
+                spec = api.make_spec(x, pol_method, backend=backend, **pol_params)
+                api.get_plan(spec)
+                jobs.append((key, arr, x, spec))
+        self._count_surface(surface)
         return order, raw_leaves, jobs, stats
 
     @staticmethod
@@ -288,8 +300,9 @@ class ExecutionEngine:
 
         def run() -> list:
             out = self._encode_bucket_sharded(codec, spec, items)
-            for (_key, arr, _x, _s), c in zip(items, out):
-                api.finish_leaf_meta(c, arr)
+            with span("hpdr.engine.finish", leaves=len(items)):
+                for (_key, arr, _x, _s), c in zip(items, out):
+                    api.finish_leaf_meta(c, arr)
             with self._lock:
                 self.sharded_leaves += len(items)
             return out
@@ -397,6 +410,10 @@ class ExecutionEngine:
         under ``self.topology`` (multi-controller mode — each host emits
         exactly the flat mapping its local shard will hold).
         """
+        with root("hpdr.engine.compress_pytree"):
+            return self._compress_pytree(tree, select, sep, owned_only)
+
+    def _compress_pytree(self, tree, select, sep, owned_only):
         order, raw_leaves, jobs, stats = self.encode_leaf_jobs(
             tree, select, sep=sep, owned_only=owned_only
         )
@@ -451,6 +468,10 @@ class ExecutionEngine:
         entropy streams packed with different ``chunk_size``) never share
         one stacked dispatch.
         """
+        with root("hpdr.engine.decompress_pytree"):
+            return self._decompress_pytree(comp, like, sep)
+
+    def _decompress_pytree(self, comp, like, sep):
         from . import api
 
         buckets = self.decode_leaf_groups(comp)
@@ -478,7 +499,9 @@ class ExecutionEngine:
             for key, val in comp.items()
         }
         leaves_with_path, treedef = jax.tree_util.tree_flatten_with_path(like)
-        out = [jnp.asarray(flat[api._path_key(p, sep)]) for p, _leaf in leaves_with_path]
+        surface = TransferStats()
+        out = [surface.upload(flat[api._path_key(p, sep)]) for p, _leaf in leaves_with_path]
+        self._count_surface(surface)
         return jax.tree_util.tree_unflatten(treedef, out)
 
     # ------------------------------------------------------------- internals
@@ -488,7 +511,7 @@ class ExecutionEngine:
 
         plan = api.get_plan(spec)
         env = CallEnv(plan)
-        c = get_codec(spec.method).encode(plan, jnp.asarray(x), env=env)
+        c = get_codec(spec.method).encode(plan, x, env=env)
         api.finish_leaf_meta(c, arr)
         with self._lock:
             self.transfer_h2d += env.transfers.h2d
@@ -504,10 +527,14 @@ class ExecutionEngine:
         plan = api.get_plan(spec)
         env = CallEnv(plan)
         out = get_codec(spec.method).decode(plan, c, env=env)
+        surface = TransferStats()
+        with span("hpdr.engine.restore", leaves=1):
+            leaf = api.restore_leaf(surface.fetch(out), c)
         with self._lock:
             self.transfer_h2d += env.transfers.h2d
             self.transfer_d2h += env.transfers.d2h
-        return api.restore_leaf(np.asarray(out), c)
+        self._count_surface(surface)
+        return leaf
 
     def _encode_bucket_sharded(self, codec, spec: ReductionSpec, items) -> list:
         """Stack same-spec leaves and drive them through the plan's compiled
@@ -524,23 +551,26 @@ class ExecutionEngine:
         from . import api
 
         plan = api.get_plan(spec)
-        stacked = np.stack([x for (_k, _a, x, _s) in items])
         k, n = len(items), len(self.devices)
         pad = (-k) % n
-        if pad:
-            stacked = np.concatenate([stacked, np.repeat(stacked[-1:], pad, 0)])
+        with span("hpdr.engine.stack", leaves=k + pad,
+                  bytes=(k + pad) * items[0][2].nbytes):
+            stacked = np.stack([x for (_k, _a, x, _s) in items])
+            if pad:
+                stacked = np.concatenate([stacked, np.repeat(stacked[-1:], pad, 0)])
         transfers = TransferStats()
         envs = [CallEnv(plan, transfers) for _ in range(k + pad)]
         state = plan.pipeline.run_batched(
             {"data": stacked}, envs, self._mesh_segment_mapper(), transfers
         )
         self._note_stack_devices(state)
-        out = [
-            codec.finish_container(
-                plan, envs[i], LeafView(state, i, envs[i], transfers)
-            )
-            for i in range(k)
-        ]
+        with span("hpdr.engine.finish", leaves=k):
+            out = [
+                codec.finish_container(
+                    plan, envs[i], LeafView(state, i, envs[i], transfers)
+                )
+                for i in range(k)
+            ]
         with self._lock:
             self.shard_map_calls += len(plan.pipeline.device_segments)
             self.transfer_h2d += transfers.h2d
@@ -577,16 +607,24 @@ class ExecutionEngine:
             transfers,
         )
         self._note_stack_devices(state)
+        surface = TransferStats()
         out = []
-        for i, (_key, c) in enumerate(items):
-            row = {key: arr[i] for key, arr in state.items()}
-            leaf = codec.finish_decode(plan, envs[i], row, c)
-            out.append(api.restore_leaf(np.asarray(leaf), c))
+        with span("hpdr.engine.restore", leaves=k):
+            for i, (_key, c) in enumerate(items):
+                row = {key: arr[i] for key, arr in state.items()}
+                leaf = codec.finish_decode(plan, envs[i], row, c)
+                out.append(api.restore_leaf(surface.fetch(leaf), c))
         with self._lock:
             self.shard_map_calls += len(plan.pipeline.inv_segments)
             self.transfer_h2d += transfers.h2d
             self.transfer_d2h += transfers.d2h
+        self._count_surface(surface)
         return out
+
+    def _count_surface(self, surface: TransferStats) -> None:
+        with self._lock:
+            self.surface_h2d += surface.h2d
+            self.surface_d2h += surface.d2h
 
     def _note_stack_devices(self, state: dict) -> None:
         ids = {
@@ -617,9 +655,6 @@ class ExecutionEngine:
         only on genuinely new shapes).
         """
 
-        def shard(a) -> P:
-            return P(*(["data"] + [None] * (np.ndim(a) - 1)))
-
         def mapper(seg, vfn, state_vals, operand_vals, ws_vals):
             donate = (
                 bool(ws_vals)
@@ -632,44 +667,8 @@ class ExecutionEngine:
                 if exe is not None:
                     self._smap_cache.move_to_end(key)
             if exe is None:
-                state_specs = tuple(shard(a) for a in state_vals)
-                op_specs = tuple(shard(a) for a in operand_vals)
-                outs_shapes, _ws_shapes = jax.eval_shape(
-                    vfn, state_vals, operand_vals, ws_vals
-                )
-                outs_specs = tuple(
-                    P(*(["data"] + [None] * (len(s.shape) - 1)))
-                    for s in outs_shapes
-                )
-                if donate:
-                    ws_specs = tuple(
-                        P(*(["data"] + [None] * np.ndim(a))) for a in ws_vals
-                    )
-
-                    def wrapped(s, o, wstack):
-                        outs, _ = vfn(s, o, tuple(w[0] for w in wstack))
-                        return outs, wstack
-
-                    exe = adapters.donating_jit(
-                        jax.shard_map(
-                            wrapped, mesh=self.mesh,
-                            in_specs=(state_specs, op_specs, ws_specs),
-                            out_specs=(outs_specs, ws_specs),
-                            check_vma=False,
-                        ),
-                        donate_argnums=(2,),
-                    )
-                else:
-                    ws_specs = tuple(
-                        P(*([None] * np.ndim(a))) for a in ws_vals
-                    )
-                    exe = jax.shard_map(
-                        lambda s, o, w: vfn(s, o, w)[0],
-                        mesh=self.mesh,
-                        in_specs=(state_specs, op_specs, ws_specs),
-                        out_specs=outs_specs,
-                        check_vma=False,
-                    )
+                exe = self._build_mapped(seg, vfn, donate, state_vals,
+                                         operand_vals, ws_vals)
                 with self._lock:
                     exe = self._smap_cache.setdefault(key, exe)
                     self._smap_cache.move_to_end(key)
@@ -680,7 +679,7 @@ class ExecutionEngine:
                         self._ws_stacks.pop(old_key, None)
             if not donate:
                 return exe(state_vals, operand_vals, ws_vals)
-            stacks = self._take_ws_stacks(key, ws_vals, vfn)
+            stacks = self._take_ws_stacks(key, ws_vals, vfn, seg)
             outs, stacks = exe(state_vals, operand_vals, stacks)
             with self._lock:
                 self._ws_stacks[key] = stacks
@@ -689,7 +688,56 @@ class ExecutionEngine:
 
         return mapper
 
-    def _take_ws_stacks(self, key: tuple, ws_vals: tuple, vfn: Callable) -> tuple:
+    def _build_mapped(self, seg, vfn, donate, state_vals, operand_vals, ws_vals):
+        """The mesh ``shard_map`` of one vmapped segment; the donating one is
+        jitted as ``jit_<segment>``."""
+
+        def shard(a) -> P:
+            return P(*(["data"] + [None] * (np.ndim(a) - 1)))
+
+        with span("hpdr.engine.build", segment=seg.name):
+            state_specs = tuple(shard(a) for a in state_vals)
+            op_specs = tuple(shard(a) for a in operand_vals)
+            outs_shapes, _ws_shapes = jax.eval_shape(
+                vfn, state_vals, operand_vals, ws_vals
+            )
+            outs_specs = tuple(
+                P(*(["data"] + [None] * (len(s.shape) - 1)))
+                for s in outs_shapes
+            )
+            if donate:
+                ws_specs = tuple(
+                    P(*(["data"] + [None] * np.ndim(a))) for a in ws_vals
+                )
+
+                def body(s, o, wstack):
+                    outs, _ = vfn(s, o, tuple(w[0] for w in wstack))
+                    return outs, wstack
+
+                body.__name__ = body.__qualname__ = seg.jit_name
+                return adapters.donating_jit(
+                    jax.shard_map(
+                        body, mesh=self.mesh,
+                        in_specs=(state_specs, op_specs, ws_specs),
+                        out_specs=(outs_specs, ws_specs),
+                        check_vma=False,
+                    ),
+                    donate_argnums=(2,),
+                )
+            # eager: its programs read ``jit(<unknown>)`` and are built
+            # anew on every call
+            ws_specs = tuple(P(*([None] * np.ndim(a))) for a in ws_vals)
+            return jax.shard_map(
+                lambda s, o, w: vfn(s, o, w)[0],
+                mesh=self.mesh,
+                in_specs=(state_specs, op_specs, ws_specs),
+                out_specs=outs_specs,
+                check_vma=False,
+            )
+
+    def _take_ws_stacks(
+        self, key: tuple, ws_vals: tuple, vfn: Callable, seg: Any
+    ) -> tuple:
         """Pop (or build) the per-shard workspace stack for a segment.
 
         Popping under the lock gives each concurrent bucket exclusive
@@ -706,9 +754,10 @@ class ExecutionEngine:
             stacks = self._ws_stacks.pop(key, None)
         if stacks is None:
             n = len(self.devices)
-            stacks = tuple(
-                jnp.stack([jnp.asarray(w)] * n) for w in ws_vals
-            )
+            with span("hpdr.engine.build", segment=seg.name):
+                stacks = tuple(
+                    jnp.stack([jnp.asarray(w)] * n) for w in ws_vals
+                )
             # no engine lock in the callback: it may fire from GC at any
             # point, and dict.pop is GIL-atomic
             weakref.finalize(vfn, self._ws_stacks.pop, key, None)
@@ -729,6 +778,8 @@ class ExecutionEngine:
                 sharded_decoded_leaves=self.sharded_decoded_leaves,
                 transfer_h2d=self.transfer_h2d,
                 transfer_d2h=self.transfer_d2h,
+                surface_h2d=self.surface_h2d,
+                surface_d2h=self.surface_d2h,
                 ws_stack_builds=self.ws_stack_builds,
                 ws_donated_calls=self.ws_donated_calls,
             )

@@ -39,6 +39,7 @@ multiples instead of retracing per byte-length).
 
 from __future__ import annotations
 
+import re
 import threading
 from dataclasses import dataclass, field
 from typing import Any, Callable, Sequence
@@ -48,6 +49,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import adapters
+from ...runtime.spans import span
 
 
 # ---------------------------------------------------------------------------
@@ -55,32 +57,41 @@ from .. import adapters
 # ---------------------------------------------------------------------------
 
 
-def _nbytes(a: Any) -> int:
-    return int(getattr(a, "nbytes", 0))
-
-
 @dataclass
 class TransferStats:
     """Host↔device byte accounting for pipeline executions.
 
-    ``d2h`` counts exactly the bytes host stages fetch plus the bytes the
-    container serialiser pulls (:meth:`LeafView.fetch`); ``h2d`` counts the
-    input staging plus operands host stages ship back.  This is the
-    observable behind the paper's 2.3%-transfer claim, emitted per codec by
-    ``scripts/check.sh bench stages``.
+    Every crossing goes through :meth:`upload` or :meth:`fetch`, which
+    count it and mark it on the trace as an ``hpdr.h2d``/``hpdr.d2h`` span
+    with its ``bytes``.  ``d2h`` counts exactly the bytes host stages fetch
+    plus the bytes the container serialiser pulls (:meth:`LeafView.fetch`);
+    ``h2d`` counts inputs that come from the host plus the operands host
+    stages ship.  An array already on the device crosses nothing and is
+    not counted.
     """
 
     h2d: int = 0
     d2h: int = 0
 
-    def count_h2d(self, *arrays: Any) -> None:
-        self.h2d += sum(_nbytes(a) for a in arrays)
+    def upload(self, value: Any) -> jax.Array:
+        """``value`` on the device; a host array's bytes count as H2D."""
+        if isinstance(value, jax.Array):
+            return value
+        value = np.asarray(value)
+        nbytes = value.size * jax.dtypes.canonicalize_dtype(value.dtype).itemsize
+        with span("hpdr.h2d", bytes=nbytes):
+            arr = jnp.asarray(value)
+        self.h2d += nbytes
+        return arr
 
-    def count_d2h(self, *arrays: Any) -> None:
-        self.d2h += sum(_nbytes(a) for a in arrays)
-
-    def as_dict(self) -> dict[str, int]:
-        return {"h2d_bytes": self.h2d, "d2h_bytes": self.d2h}
+    def fetch(self, value: Any) -> np.ndarray:
+        """``value`` on the host; a device array's bytes count as D2H."""
+        if not isinstance(value, jax.Array):
+            return np.asarray(value)
+        with span("hpdr.d2h", bytes=value.nbytes):
+            out = np.asarray(value)
+        self.d2h += out.nbytes
+        return out
 
 
 # ---------------------------------------------------------------------------
@@ -296,6 +307,12 @@ class _Segment:
         base = sep.join(st.name for st in self.stages)
         return base if self.direction == "fwd" else f"invert[{base}]"
 
+    @property
+    def jit_name(self) -> str:
+        """:attr:`name` as an identifier: the segment's jitted module reads
+        ``jit_<jit_name>`` on the trace and in compile logs."""
+        return re.sub(r"\W+", "_", self.name).strip("_")
+
 
 def _dedup(items) -> tuple:
     seen, out = set(), []
@@ -475,6 +492,7 @@ class CompiledPipeline:
                 return outs
             return outs, tuple(env._workspace[k] for k in seg.workspace_keys)
 
+        fn.__name__ = fn.__qualname__ = seg.jit_name
         return fn
 
     def segment_exe(self, seg: _Segment, statics: dict, batched: bool) -> Callable:
@@ -514,15 +532,13 @@ class CompiledPipeline:
         self,
         state0: dict[str, Any],
         env: CallEnv | None = None,
-        profile: dict[str, float] | None = None,
         workspace: dict[str, Any] | None = None,
     ) -> tuple[dict[str, Any], CallEnv]:
         """Execute the encode direction for one leaf.
 
-        Device segments run as single fused dispatches; host stages fetch
-        exactly their declared keys (counted in ``env.transfers``).  When
-        ``profile`` is given, per-stage wall times accumulate into it keyed
-        by stage name (device results are blocked on for honest timings).
+        Device segments run as single fused dispatches, each in an
+        ``hpdr.segment`` span; host stages fetch exactly their declared keys
+        (counted in ``env.transfers``) inside an ``hpdr.host_stage`` span.
 
         ``workspace`` overrides the plan's shared workspace buffers with a
         caller-owned dict — the chunk-pipelined scheduler passes one such
@@ -533,14 +549,16 @@ class CompiledPipeline:
         """
         plan = self.plan
         env = env or CallEnv(plan)
-        env.transfers.count_h2d(*state0.values())
-        state = {k: jnp.asarray(v) for k, v in state0.items()}
-        shipped: set[str] = set()
+        state = {k: env.transfers.upload(v) for k, v in state0.items()}
         for step in self.steps:
-            t0 = _clock() if profile is not None else 0.0
-            if isinstance(step, _Segment):
+            if not isinstance(step, _Segment):
+                with span("hpdr.host_stage", stage=step.name):
+                    fetched = {k: env.transfers.fetch(state[k]) for k in step.fetches}
+                    step.host_apply(env, fetched)
+                continue
+            with span("hpdr.segment", segment=step.name):
                 operand_vals = tuple(
-                    self._ship(env, k, shipped) for k in step.operand_keys
+                    self._ship(env, k) for k in step.operand_keys
                 )
                 exe = self.segment_exe(step, env.statics, batched=False)
                 state_vals = tuple(state[k] for k in step.in_keys)
@@ -568,24 +586,25 @@ class CompiledPipeline:
                 else:
                     outs, _ = exe(state_vals, operand_vals, ())
                 state.update(zip(step.out_keys, outs))
-                if profile is not None:
-                    jax.block_until_ready(outs)
-            else:
-                fetched = {k: np.asarray(state[k]) for k in step.fetches}
-                env.transfers.count_d2h(*fetched.values())
-                step.host_apply(env, fetched)
-            if profile is not None:
-                profile[step.name] = profile.get(step.name, 0.0) + (_clock() - t0)
         return state, env
 
-    def _ship(self, env: CallEnv, name: str, shipped: set[str]) -> jax.Array:
-        val = env.operands[name]
-        arr = jnp.asarray(val)
-        if name not in shipped:
-            env.transfers.count_h2d(arr)
-            shipped.add(name)
-        env.operands[name] = arr
+    @staticmethod
+    def _ship(env: CallEnv, name: str) -> jax.Array:
+        """A host stage's operand on the device; the first use of a call
+        uploads it and later uses find it there."""
+        arr = env.operands[name] = env.transfers.upload(env.operands[name])
         return arr
+
+    @staticmethod
+    def _upload_stacked(transfers: TransferStats, envs: list[CallEnv],
+                        keys: tuple[str, ...], stacked: dict) -> tuple:
+        """Per-leaf operands ``keys``, stacked and uploaded once a batch."""
+        for k in keys:
+            if k not in stacked:
+                stacked[k] = transfers.upload(_stack_pad(
+                    [np.asarray(e.operands[k]) for e in envs]
+                ))
+        return tuple(stacked[k] for k in keys)
 
     # -- execution: stacked batch (engine shard_map path) --------------------
 
@@ -606,20 +625,24 @@ class CompiledPipeline:
         (:meth:`Stage.merge_static`) before the next segment is specialised.
         """
         plan = self.plan
-        transfers.count_h2d(*state0.values())
-        state = {k: jnp.asarray(v) for k, v in state0.items()}
+        state = {k: transfers.upload(v) for k, v in state0.items()}
         merged: dict[str, int] = dict(envs[0].statics)
         stacked_ops: dict[str, jax.Array] = {}
         for step in self.steps:
-            if isinstance(step, _Segment):
-                for k in step.operand_keys:
-                    if k not in stacked_ops:
-                        arr = jnp.asarray(_stack_pad(
-                            [np.asarray(e.operands[k]) for e in envs]
-                        ))
-                        transfers.count_h2d(arr)
-                        stacked_ops[k] = arr
-                operand_vals = tuple(stacked_ops[k] for k in step.operand_keys)
+            if not isinstance(step, _Segment):
+                with span("hpdr.host_stage", stage=step.name):
+                    fetched = {k: transfers.fetch(state[k]) for k in step.fetches}
+                    for i, env in enumerate(envs):
+                        step.host_apply(env, {k: fetched[k][i] for k in step.fetches})
+                for name in step.static_outputs:
+                    merged[name] = step.merge_static(
+                        name, [env.statics[name] for env in envs]
+                    )
+                continue
+            with span("hpdr.segment", segment=step.name):
+                operand_vals = self._upload_stacked(
+                    transfers, envs, step.operand_keys, stacked_ops
+                )
                 vfn = self.segment_exe(step, merged, batched=True)
                 state_vals = tuple(state[k] for k in step.in_keys)
                 if step.workspace_keys:
@@ -638,15 +661,6 @@ class CompiledPipeline:
                 else:
                     outs = device_mapper(step, vfn, state_vals, operand_vals, ())
                 state.update(zip(step.out_keys, outs))
-            else:
-                fetched = {k: np.asarray(state[k]) for k in step.fetches}
-                transfers.count_d2h(*fetched.values())
-                for i, env in enumerate(envs):
-                    step.host_apply(env, {k: fetched[k][i] for k in step.fetches})
-                for name in step.static_outputs:
-                    merged[name] = step.merge_static(
-                        name, [env.statics[name] for env in envs]
-                    )
         return state
 
     @property
@@ -673,7 +687,6 @@ class CompiledPipeline:
         self,
         state0: dict[str, Any],
         env: CallEnv | None = None,
-        profile: dict[str, float] | None = None,
     ) -> tuple[dict[str, Any], CallEnv]:
         """Execute the decode direction for one leaf.
 
@@ -691,36 +704,31 @@ class CompiledPipeline:
         plan = self.plan
         env = env or CallEnv(plan)
         for st in self.inv_preps:
-            t0 = _clock() if profile is not None else 0.0
-            st.host_prepare(env)
-            if profile is not None:
-                profile[st.name] = profile.get(st.name, 0.0) + (_clock() - t0)
-        env.transfers.count_h2d(*state0.values())
-        state = self._pad_state({k: jnp.asarray(v) for k, v in state0.items()})
-        shipped: set[str] = set()
+            with span("hpdr.host_stage", stage=st.name):
+                st.host_prepare(env)
+        state = self._pad_state(
+            {k: env.transfers.upload(v) for k, v in state0.items()}
+        )
         for seg in self.inv_segments:
-            t0 = _clock() if profile is not None else 0.0
-            operand_vals = tuple(
-                self._ship(env, k, shipped) for k in seg.operand_keys
-            )
-            exe = self.segment_exe(seg, env.statics, batched=False)
-            state_vals = tuple(state[k] for k in seg.in_keys)
-            if seg.workspace_keys:
-                # workspace read under the lock — see run() for the
-                # use-after-donate rationale
-                with plan.lock:
-                    ws_vals = tuple(
-                        plan.workspace[k] for k in seg.workspace_keys
-                    )
-                    outs, ws_out = exe(state_vals, operand_vals, ws_vals)
-                    for k, buf in zip(seg.workspace_keys, ws_out):
-                        plan.recycle(k, buf)
-            else:
-                outs, _ = exe(state_vals, operand_vals, ())
-            state.update(zip(seg.out_keys, outs))
-            if profile is not None:
-                jax.block_until_ready(outs)
-                profile[seg.name] = profile.get(seg.name, 0.0) + (_clock() - t0)
+            with span("hpdr.segment", segment=seg.name):
+                operand_vals = tuple(
+                    self._ship(env, k) for k in seg.operand_keys
+                )
+                exe = self.segment_exe(seg, env.statics, batched=False)
+                state_vals = tuple(state[k] for k in seg.in_keys)
+                if seg.workspace_keys:
+                    # workspace read under the lock — see run() for the
+                    # use-after-donate rationale
+                    with plan.lock:
+                        ws_vals = tuple(
+                            plan.workspace[k] for k in seg.workspace_keys
+                        )
+                        outs, ws_out = exe(state_vals, operand_vals, ws_vals)
+                        for k, buf in zip(seg.workspace_keys, ws_out):
+                            plan.recycle(k, buf)
+                else:
+                    outs, _ = exe(state_vals, operand_vals, ())
+                state.update(zip(seg.out_keys, outs))
         return state, env
 
     def invert_batched(
@@ -742,8 +750,9 @@ class CompiledPipeline:
         """
         plan = self.plan
         for st in self.inv_preps:
-            for env in envs:
-                st.host_prepare(env)
+            with span("hpdr.host_stage", stage=st.name):
+                for env in envs:
+                    st.host_prepare(env)
         merged: dict[str, int] = dict(envs[0].statics)
         for st in self.inv_preps:
             for name in st.inv_static_outputs:
@@ -764,39 +773,27 @@ class CompiledPipeline:
                     [arr, np.full((arr.shape[0], pad) + arr.shape[2:],
                                   fills.get(key, 0), arr.dtype)], axis=1,
                 )
-            a = jnp.asarray(arr)
-            transfers.count_h2d(a)
-            state[key] = a
+            state[key] = transfers.upload(arr)
         stacked_ops: dict[str, jax.Array] = {}
         for seg in self.inv_segments:
-            for k in seg.operand_keys:
-                if k not in stacked_ops:
-                    arr = jnp.asarray(_stack_pad(
-                        [np.asarray(e.operands[k]) for e in envs]
-                    ))
-                    transfers.count_h2d(arr)
-                    stacked_ops[k] = arr
-            operand_vals = tuple(stacked_ops[k] for k in seg.operand_keys)
-            vfn = self.segment_exe(seg, merged, batched=True)
-            state_vals = tuple(state[k] for k in seg.in_keys)
-            if seg.workspace_keys:
-                with plan.lock:
-                    ws_vals = tuple(
-                        plan.workspace[k] for k in seg.workspace_keys
-                    )
-                    outs = device_mapper(
-                        seg, vfn, state_vals, operand_vals, ws_vals
-                    )
-            else:
-                outs = device_mapper(seg, vfn, state_vals, operand_vals, ())
-            state.update(zip(seg.out_keys, outs))
+            with span("hpdr.segment", segment=seg.name):
+                operand_vals = self._upload_stacked(
+                    transfers, envs, seg.operand_keys, stacked_ops
+                )
+                vfn = self.segment_exe(seg, merged, batched=True)
+                state_vals = tuple(state[k] for k in seg.in_keys)
+                if seg.workspace_keys:
+                    with plan.lock:
+                        ws_vals = tuple(
+                            plan.workspace[k] for k in seg.workspace_keys
+                        )
+                        outs = device_mapper(
+                            seg, vfn, state_vals, operand_vals, ws_vals
+                        )
+                else:
+                    outs = device_mapper(seg, vfn, state_vals, operand_vals, ())
+                state.update(zip(seg.out_keys, outs))
         return state
-
-
-def _clock() -> float:
-    import time
-
-    return time.perf_counter()
 
 
 def _stack_pad(arrs: list[np.ndarray], fill: int = 0) -> np.ndarray:
@@ -851,6 +848,4 @@ class LeafView:
             arr = arr[self.index]
         if length is not None:
             arr = arr[:length]
-        out = np.asarray(arr)
-        self.transfers.count_d2h(out)
-        return out
+        return self.transfers.fetch(arr)

@@ -24,6 +24,7 @@ without deadlocking the pool).
 
 from __future__ import annotations
 
+import contextvars
 import itertools
 import threading
 import time
@@ -31,6 +32,8 @@ from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Any, Callable, Sequence
 
 import jax
+
+from .spans import span
 
 COMPUTE, IO = "compute", "io"
 
@@ -133,7 +136,9 @@ class DeviceExecutor:
         no default-device pin — for tasks that span the whole mesh (stacked
         shard_map buckets).  ``priority`` is an optional caller label
         accumulated into :meth:`priority_stats` (the serving layer tags
-        interactive vs bulk work).
+        interactive vs bulk work).  The task runs in a copy of the caller's
+        context, so its spans carry the ``call`` of the entry point that
+        submitted it.
         """
         if lane == IO:
             pool, dev = self._io_pool, None
@@ -157,7 +162,8 @@ class DeviceExecutor:
         out: Future = Future()
         try:
             pool.submit(
-                self._run, out, dev, lane_key, priority, t_sub, fn, args, kwargs
+                self._run, out, dev, lane_key, priority, t_sub,
+                contextvars.copy_context(), fn, args, kwargs,
             )
         except RuntimeError as e:
             # lost the race with a concurrent shutdown(): undo the counters
@@ -203,6 +209,7 @@ class DeviceExecutor:
         the returned :class:`Submission` without running ``fn``.
         """
         out: Future = Future()
+        ctx = contextvars.copy_context()  # the caller's call, for the spans
 
         def _copy(src: Future) -> None:
             exc = src.exception()
@@ -217,8 +224,8 @@ class DeviceExecutor:
                 out.set_exception(exc)
                 return
             try:
-                inner = self.submit(
-                    fn, upstream.result(), *args,
+                inner = ctx.run(
+                    self.submit, fn, upstream.result(), *args,
                     device=device, lane=lane, priority=priority, **kwargs
                 )
             except BaseException as e:  # e.g. pool already shut down —
@@ -233,7 +240,8 @@ class DeviceExecutor:
 
     def _run(
         self, out: Future, device: Any, lane: str, priority: str | None,
-        t_sub: float, fn: Callable, args: tuple, kwargs: dict,
+        t_sub: float, ctx: contextvars.Context, fn: Callable, args: tuple,
+        kwargs: dict,
     ) -> None:
         t_start = time.perf_counter()
         with self._lock:
@@ -245,11 +253,9 @@ class DeviceExecutor:
                 e["wait_s"] += t_start - t_sub
         try:
             try:
-                if device is None:
-                    res = fn(*args, **kwargs)
-                else:
-                    with jax.default_device(device):
-                        res = fn(*args, **kwargs)
+                res = ctx.run(
+                    self._task, device, lane, t_start - t_sub, fn, args, kwargs
+                )
             except BaseException as exc:
                 out.set_exception(exc)
             else:
@@ -265,6 +271,15 @@ class DeviceExecutor:
                 if priority is not None:
                     self._prio_entry(priority)["completed"] += 1
                 self._idle.notify_all()
+
+    @staticmethod
+    def _task(device: Any, lane: str, wait_s: float, fn: Callable,
+              args: tuple, kwargs: dict) -> Any:
+        with span("hpdr.executor.task", lane=lane, wait_us=int(wait_s * 1e6)):
+            if device is None:
+                return fn(*args, **kwargs)
+            with jax.default_device(device):
+                return fn(*args, **kwargs)
 
     def map(self, fn: Callable, items: Sequence[Any]) -> list[Any]:
         """Fan ``fn`` over ``items`` across the device ring; ordered results."""

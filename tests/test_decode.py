@@ -38,7 +38,8 @@ from repro.core import adapters, api, huffman
 from repro.core.codecs import get_codec
 from repro.core.codecs.huffman_codec import stream_decode_index
 from repro.core.engine import ExecutionEngine
-from conftest import smooth_field_3d
+from repro.core.stages import CallEnv
+from conftest import smooth_field_3d, span_seconds
 
 
 def _strip_decode_index(c):
@@ -206,12 +207,16 @@ def test_stacked_decode_falls_back_for_old_streams(rng):
 # ---------------------------------------------------------------------------
 
 
-def test_decode_transfers_are_stream_plus_metadata(rng):
+def test_decode_transfers_are_stream_plus_metadata(rng, trace_spans):
     keys = np.minimum(np.abs(rng.normal(0, 6, 1 << 16)).astype(np.int32), 63)
     spec = api.make_spec(keys, "huffman")
     c = api.encode(spec, jnp.asarray(keys))
-    api.decode_profiled(c)  # warm
-    out, stage_s, transfers = api.decode_profiled(c)
+    codec = get_codec("huffman")
+    plan = api.get_plan(codec.decode_spec(c))
+    codec.decode(plan, c)  # warm
+    env = CallEnv(plan)
+    out, spans = trace_spans(lambda: codec.decode(plan, c, env=env))
+    transfers, stage_s = env.transfers, span_seconds(spans)
     np.testing.assert_array_equal(np.asarray(out), keys)
     # H2D: the compressed sections plus metadata-scale decode operands —
     # far below the raw array the decode produces

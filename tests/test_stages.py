@@ -32,8 +32,8 @@ from repro.core import api, huffman, mgard
 from repro.core.codecs import get_codec
 from repro.core.context import GLOBAL_CMM
 from repro.core.engine import ExecutionEngine
-from repro.core.stages import StageGraph, Stage
-from conftest import smooth_field_3d
+from repro.core.stages import CallEnv, StageGraph, Stage
+from conftest import smooth_field_3d, span_seconds
 
 
 # ---------------------------------------------------------------------------
@@ -344,14 +344,19 @@ def test_engine_stacked_multidevice_subprocess():
 # ---------------------------------------------------------------------------
 
 
-def test_encode_transfers_are_metadata_plus_stream(rng):
+def test_encode_transfers_are_metadata_plus_stream(rng, trace_spans):
     """The encode path never stages the raw array back to host: D2H is the
     compressed stream plus metadata-scale barrier fetches."""
     keys = np.minimum(np.abs(rng.normal(0, 6, 1 << 16)).astype(np.int32), 63)
     spec = api.make_spec(keys, "huffman")
-    api.encode_profiled(spec, jnp.asarray(keys))  # warm
-    c, stage_s, transfers = api.encode_profiled(spec, jnp.asarray(keys))
+    codec, plan = get_codec("huffman"), api.get_plan(spec)
+    codec.encode(plan, jnp.asarray(keys))  # warm
+    env = CallEnv(plan)
+    c, spans = trace_spans(lambda: codec.encode(plan, jnp.asarray(keys), env=env))
+    transfers = env.transfers
     assert transfers.d2h < keys.nbytes / 2      # << raw input
     assert transfers.d2h >= c.nbytes() - c.arrays["length_table"].nbytes
+    stage_s = span_seconds(spans)
     assert set(stage_s) >= {"codebook_build", "huffman_entropy+bit_pack"}
     assert stage_s["codebook_build"] > 0
+
