@@ -14,12 +14,14 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU compiler's library.
 """
 
+import math
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import mgard
+from repro.core import mgard, zfp
 from repro.kernels.histogram import kernel as hist_k
 from repro.kernels.huffman_decode import kernel as dec_k
 from repro.kernels.huffman_encode import kernel as enc_k
@@ -33,6 +35,11 @@ STACK = 4                   # leaves in a stacked engine bucket
 LEVELS = 10                 # per-level bins of a 513³ grid: L + 1 with L = 9
 CHUNK = 4096                # huffman.DEFAULT_CHUNK
 HBM_BYTES = 16e9            # one v5e chip
+ZFP_FIELDS = {              # name → (field shape, leaves stacked under vmap)
+    "nyx": (NYX, None),
+    "leaves": ((16384, 32, 32), 8),   # eight 256³ leaves as the engine re-blocks them
+    "flat": ((1 << 27,), None),
+}
 
 
 @pytest.fixture(scope="module")
@@ -152,4 +159,22 @@ def test_mgard_recompose_compiles_for_v5e(one_chip):
         lambda c: mgard.recompose(c, shape=NYX), [((513,) * 3, jnp.float32)],
         one_chip,
     )
+    assert _fits(compiled)
+
+
+@pytest.mark.parametrize("name", sorted(ZFP_FIELDS))
+def test_zfp_compress_compiles_for_v5e(name, one_chip):
+    """ZFP's field blocking and its kernel fit the chip: no intermediate of
+    the blocking pads a narrow minor axis out to the (8, 128) tile."""
+    shape, stack = ZFP_FIELDS[name]
+    n = math.prod(shape)
+
+    def fn(x):
+        return zfp.compress_jit(x, 16, len(shape), shape, adapter="pallas")
+
+    args = [((n,), jnp.float32)]
+    if stack:
+        fn, args = jax.vmap(fn), [((stack, n), jnp.float32)]
+    compiled = _compile(fn, args, one_chip)
+    assert "tpu_custom_call" in compiled.as_text()
     assert _fits(compiled)

@@ -1,10 +1,15 @@
 """ZFP-X: transform inversion, rate behaviour, roundtrip error decay."""
 
-import numpy as np
+import itertools
+
+import jax
 import jax.numpy as jnp
+import numpy as np
+import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core import zfp
+from repro.core.abstractions import pad_to_blocks
 from conftest import smooth_field_3d
 
 
@@ -83,3 +88,41 @@ def test_zero_and_constant_blocks():
         z = zfp.compress(jnp.asarray(data), rate=16)
         out = np.asarray(zfp.decompress(z))
         assert np.abs(out - data).max() <= max(abs(val) * 1e-4, 1e-30)
+
+
+def _blocks_by_strided_slices(padded):
+    """The layout's definition: one stride-4 slice per in-block offset."""
+    planes = [padded[tuple(slice(o, None, 4) for o in off)]
+              for off in itertools.product(range(4), repeat=padded.ndim)]
+    return jnp.stack(planes).reshape(len(planes), -1)
+
+
+@pytest.mark.parametrize("shape", [
+    (16,), (13,), (1030,), (8, 12), (7, 10), (8, 12, 16), (5, 9, 6),
+    (4, 8, 12, 16), (3, 5, 6, 7),
+])
+def test_field_to_blocks_matches_strided_slices(shape, rng):
+    """Blocking equals its strided-slice definition, and
+    :func:`zfp.blocks_to_field` inverts it exactly, edge padding included."""
+    data = rng.normal(size=shape).astype(np.float32)
+    padded = pad_to_blocks(jnp.asarray(data), (4,) * len(shape))
+    blocks = np.asarray(zfp.field_to_blocks(padded))
+    expect = np.asarray(_blocks_by_strided_slices(padded))
+    assert blocks.shape == expect.shape
+    assert (blocks == expect).all()
+    back = zfp.blocks_to_field(jnp.asarray(blocks), tuple(padded.shape))
+    assert (np.asarray(back) == np.asarray(padded)).all()
+
+
+@pytest.mark.parametrize("shape", [(4096,), (64, 64), (64, 64, 64), (16, 16, 16, 16)])
+def test_field_to_blocks_has_no_strided_access(shape):
+    """A stride-4 slice or gather on the minor axis is the TPU's worst case
+    (41 ms for 8 MiB of a 512³ field on a v5e): blocking moves data with
+    reshapes and transposes only."""
+    jaxpr = jax.make_jaxpr(zfp.field_to_blocks)(
+        jax.ShapeDtypeStruct(shape, jnp.float32))
+    prims = [e.primitive.name for e in jaxpr.jaxpr.eqns]
+    assert "gather" not in prims
+    strided = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "slice"
+               and any(s != 1 for s in (e.params["strides"] or ()))]
+    assert not strided
